@@ -56,6 +56,7 @@ def _start_server(tmp: pathlib.Path, k: int) -> subprocess.Popen:
         "--journal", str(tmp / "journal.jsonl"),
         "--snapshot-dir", str(tmp / "snaps"),
         "--nodes", str(k), "--seed", "0",
+        "--backend", "reference",
     ]
     env = dict(os.environ, PYTHONPATH=str(_REPO_ROOT / "src"))
     proc = subprocess.Popen(cmd, stdout=subprocess.PIPE, text=True, env=env)
@@ -108,12 +109,9 @@ def run() -> list[dict]:
     profile = _profile(k)
     envs = environment_trace(n_reqs, seed=13)
 
-    # --- in-process baseline ---------------------------------------------
-    broker = OffloadBroker(backend="reference", clock=lambda: 0.0)
-    broker.register("app", profile, ResponseTimeModel())
-    local = _measure(broker.submit, broker.tick, envs)
-
     # --- cross-process over a unix socket --------------------------------
+    # The solver child runs first and alone: this process touches no
+    # device until the child has exited (one process per chip).
     import tempfile
 
     with tempfile.TemporaryDirectory(prefix="bench_ipc_") as tmp_s:
@@ -135,6 +133,11 @@ def run() -> list[dict]:
         finally:
             proc.kill()
             proc.wait()
+
+    # --- in-process baseline ---------------------------------------------
+    broker = OffloadBroker(backend="reference", clock=lambda: 0.0)
+    broker.register("app", profile, ResponseTimeModel())
+    local = _measure(broker.submit, broker.tick, envs)
 
     # replies across the wire must be the in-process replies, bit for bit
     if remote["sigs"] != local["sigs"]:

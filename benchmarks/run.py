@@ -48,6 +48,8 @@ import subprocess
 import sys
 import time
 
+from repro.launch.compile_cache import use_compile_cache
+
 from benchmarks import (
     broker,
     complexity,
@@ -268,9 +270,10 @@ def _smoke_check_trajectory(path: pathlib.Path, benchmark: str) -> None:
                 f"{d_max} devices is below the {need:.1f}x bar "
                 f"({cores} cores)"
             )
-        if "shard/kernel_compiled" not in by_name:
+        on_tpu = (last.get("env") or {}).get("jax_backend") == "tpu"
+        if on_tpu and "shard/kernel_compiled" not in by_name:
             raise RuntimeError(
-                f"{path.name}: last run lacks the shard/kernel_compiled row"
+                f"{path.name}: TPU run lacks the shard/kernel_compiled row"
             )
     if benchmark == "ipc":
         # ISSUE-10 acceptance: both passes present, and cross-process
@@ -307,6 +310,7 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", help="comma-separated subset of benchmarks")
     args = ap.parse_args(argv)
+    use_compile_cache()
     names = args.only.split(",") if args.only else list(MODULES)
 
     print("name,us_per_call,derived")

@@ -27,13 +27,17 @@ append — the exact torn write the warm-restart path must absorb.
 """
 
 import argparse
+import json
 import os
 import signal
 import sys
 
+import jax
 import numpy as np
 
 from repro.core import AppProfile, ResponseTimeModel, random_wcg
+from repro.kernels.ops import default_interpret
+from repro.launch.compile_cache import use_compile_cache
 from repro.obs.metrics import MetricsRegistry
 from repro.obs.trace import Tracer
 from repro.service import OffloadBroker, SolverServer, tcp_address, unix_address
@@ -59,8 +63,9 @@ def main(argv=None) -> int:
     ap.add_argument("--tenant", default="app")
     ap.add_argument("--nodes", type=int, default=12, help="demo WCG size")
     ap.add_argument("--seed", type=int, default=0, help="demo WCG seed")
-    ap.add_argument("--backend", default="reference",
-                    choices=("reference", "jax", "pallas"))
+    ap.add_argument("--backend", default="jax",
+                    choices=("reference", "jax", "pallas"),
+                    help="solver for every flush (reference = numpy oracle)")
     ap.add_argument("--batch-capacity", type=int, default=0,
                     help="also expose a batch session group of this size")
     ap.add_argument("--max-ticks", type=int, default=None,
@@ -71,6 +76,7 @@ def main(argv=None) -> int:
     ap.add_argument("--kill-at-tick", type=int, default=None,
                     help="crash hook: SIGKILL self mid-tick N")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     if bool(args.socket) == bool(args.tcp):
         ap.error("exactly one of --socket / --tcp is required")
@@ -80,7 +86,8 @@ def main(argv=None) -> int:
         host, _, port = args.tcp.partition(":")
         address = tcp_address(host or "127.0.0.1", int(port or 0))
 
-    broker = OffloadBroker(backend=args.backend, clock=lambda: 0.0)
+    tracer = Tracer() if (args.trace or args.trace_jsonl) else None
+    broker = OffloadBroker(backend=args.backend, clock=lambda: 0.0, tracer=tracer)
     profile, cost_model = demo_tenant(args.nodes, args.seed)
     broker.register(args.tenant, profile, cost_model)
 
@@ -95,7 +102,6 @@ def main(argv=None) -> int:
 
         broker.tick = tick_then_die
 
-    tracer = Tracer() if (args.trace or args.trace_jsonl) else None
     server = SolverServer(
         broker,
         address=address,
@@ -110,8 +116,17 @@ def main(argv=None) -> int:
     if args.batch_capacity > 0:
         broker.register_batch(args.tenant, args.batch_capacity)
     # READY is the startup barrier the tests/CI wait on; the address
-    # matters for --tcp with an ephemeral port
+    # matters for --tcp with an ephemeral port.  DEVICE names what the
+    # solves run on, so a caller can refuse a host fallback.
+    device = jax.devices()[0]
+    facts = {
+        "platform": device.platform,
+        "kind": device.device_kind,
+        "count": jax.device_count(),
+        "interpret": default_interpret(),
+    }
     print(f"RECOVERED {recovered}", flush=True)
+    print(f"DEVICE {json.dumps(facts)}", flush=True)
     print(f"READY {' '.join(str(p) for p in bound)}", flush=True)
     try:
         server.serve_forever(max_ticks=args.max_ticks)

@@ -38,9 +38,9 @@ Backend-selection story — when each wins:
   runs ``repro.kernels.mcop_phase.mcop_stoer_wagner_kernel``: the full
   |V|−1-phase solve (merges included) inside one Pallas kernel with a
   grid dimension over the batch, so the adjacency is loaded HBM→VMEM once
-  per solve.  Wins on TPU where the phase loop is bandwidth-bound on
-  adjacency row reads; on CPU it falls back to interpret mode (correct
-  but slow — benchmark numbers there are indicative only).
+  per solve.  Its speed against the XLA path is not measured on a chip;
+  on CPU it falls back to interpret mode (correct but slow — benchmark
+  numbers there are indicative only).
 
 * :func:`mcop_batch` also accepts a :class:`~repro.core.graph.WCGBatch`
   directly — consumers that already hold stacked tensors (the cost

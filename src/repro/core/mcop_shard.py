@@ -39,7 +39,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.launch.mesh import make_solver_mesh
@@ -161,14 +160,14 @@ def _sharded_dispatch(mesh: Mesh, backend: str, interpret: bool | None):
         def solve(adj, wl, wc, pin):
             return _dispatch_arrays(adj, wl, wc, pin, backend, interpret)
 
-        # check_rep=False: the bodies contain while_loop / pallas_call,
-        # which shard_map's replication checker cannot see through.
-        sharded = shard_map(
+        # check_vma=False: the bodies contain while_loop / pallas_call,
+        # which shard_map's varying-axes checker cannot see through.
+        sharded = jax.shard_map(
             solve,
             mesh=mesh,
             in_specs=(spec, spec, spec, spec),
             out_specs=(spec, spec),
-            check_rep=False,
+            check_vma=False,
         )
         donate = (0, 1, 2, 3) if _donate(mesh) else ()
         fn = _SHARDED_DISPATCH_CACHE[key] = jax.jit(
@@ -258,12 +257,12 @@ def sharded_fused_solver(build_solve, mesh: Mesh, env_struct):
     env_specs = jax.tree_util.tree_unflatten(
         env_struct, [spec] * env_struct.num_leaves
     )
-    sharded = shard_map(
+    sharded = jax.shard_map(
         build_solve,
         mesh=mesh,
         in_specs=(P(), P(), P(), P(), env_specs),
         out_specs=(spec, spec),
-        check_rep=False,
+        check_vma=False,
     )
     # donate the env columns (the per-tick varying buffers); the profile
     # tensors are replicated constants the caller reuses across ticks

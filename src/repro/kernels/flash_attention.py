@@ -30,12 +30,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # TPU memory-space handles; absent on some CPU-only builds
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    _VMEM = pl.MemorySpace.ANY  # type: ignore[attr-defined]
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["flash_attention_kernel"]
 
@@ -162,9 +157,9 @@ def flash_attention_kernel(
         ),
         out_shape=jax.ShapeDtypeStruct((b, h, nq * block_q, hd), q.dtype),
         scratch_shapes=[
-            _VMEM((block_q, hd), jnp.float32),
-            _VMEM((block_q, 1), jnp.float32),
-            _VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, hd), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
         ],
         interpret=interpret,
     )(q, k, v)

@@ -39,12 +39,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    _VMEM = pl.MemorySpace.ANY  # type: ignore[attr-defined]
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["mamba_chunk_scan_kernel"]
 
@@ -148,7 +143,7 @@ def mamba_chunk_scan_kernel(
             jax.ShapeDtypeStruct((b, h, nc, q, p), jnp.float32),
             jax.ShapeDtypeStruct((b, h, p, n), jnp.float32),
         ],
-        scratch_shapes=[_VMEM((p, n), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
         interpret=interpret,
     )(x, dt, ld, bm, cm, h0)
     return y, h_final

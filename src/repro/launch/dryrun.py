@@ -165,7 +165,6 @@ def _measure_cell(cfg, shape, mesh, *, unroll_layers: bool = False, **build_kw) 
     visible to cost_analysis — required by the depth probes (a rolled scan
     of length 2 is still a while loop counted once).
     """
-    from repro.launch.mesh import use_mesh
     from repro.launch.specs import build_cell
     from repro.models import attention as attn_lib
     from repro.models import transformer as tf
@@ -180,7 +179,7 @@ def _measure_cell(cfg, shape, mesh, *, unroll_layers: bool = False, **build_kw) 
     shard_lib.set_expert_sharding(expert_mode)
     try:
         cell = build_cell(cfg, shape, mesh, **build_kw)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             jitted = jax.jit(
                 cell.step_fn,
                 in_shardings=cell.in_shardings,
@@ -243,7 +242,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool, n_micro: int = 1,
              decode_flash: bool = False, expert_mode: str = "ep_model",
              verbose: bool = True) -> dict:
     from repro.configs import get_config, get_shape
-    from repro.launch.mesh import make_production_mesh, use_mesh
+    from repro.launch.mesh import make_production_mesh
     from repro.launch.specs import build_cell
 
     cfg = get_config(arch)
@@ -268,7 +267,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool, n_micro: int = 1,
 
     attn_lib.set_decode_flash_partitioning(decode_flash)
     try:
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             jitted = jax.jit(
                 cell.step_fn,
                 in_shardings=cell.in_shardings,

@@ -17,7 +17,6 @@ __all__ = [
     "make_production_mesh",
     "make_local_mesh",
     "make_solver_mesh",
-    "use_mesh",
     "POD_CHIPS",
 ]
 
@@ -25,12 +24,9 @@ POD_CHIPS = 256  # one v5e pod = 16×16
 
 
 def _mk(shape, axes) -> Mesh:
-    # axis_types landed after jax 0.4.x; fall back to the plain signature
-    # so the mesh builders work across the jax versions the repo supports.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -47,20 +43,6 @@ def make_local_mesh(*, data: int | None = None, model: int = 1) -> Mesh:
         data = n // model
     assert data * model == n, (data, model, n)
     return _mk((data, model), ("data", "model"))
-
-
-def use_mesh(mesh: Mesh):
-    """Context manager activating ``mesh`` for sharded jit compilation.
-
-    ``jax.set_mesh`` where it exists; on the older jax line the ``Mesh``
-    object is itself the equivalent context manager (it installs the
-    axis-resource environment ``in_shardings``/``out_shardings`` compile
-    against).
-    """
-    setter = getattr(jax, "set_mesh", None)
-    if setter is not None:
-        return setter(mesh)
-    return mesh
 
 
 def make_solver_mesh(devices=None) -> Mesh:

@@ -25,25 +25,17 @@ computes" energy term (§4.3.2).
 Within a stage, tensors stay sharded over ("data", "model") exactly as in
 the non-pipelined path — shard_map only manages the "pod" axis; the body
 re-enters the auto-sharding world for the other axes via
-``jax.experimental.shard_map``'s ``check_rep=False`` escape.
+``jax.shard_map``'s ``check_vma=False`` escape.
 """
 
 from __future__ import annotations
 
 import functools
-import inspect
 from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:  # JAX moved shard_map out of experimental in 0.6+
-    from jax import shard_map as _shard_map_mod  # type: ignore
-
-    shard_map = _shard_map_mod  # jax.shard_map is the function itself
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map  # type: ignore
 
 __all__ = ["stack_stage_params", "pipeline_apply", "pipeline_spec_for"]
 
@@ -88,20 +80,12 @@ def pipeline_apply(
     data_axes = tuple(a for a in ("data",) if a in other_axes)
     x_spec = P(data_axes if data_axes else None)
 
-    # the replication-check escape hatch was renamed check_rep→check_vma
-    # across jax versions; pass whichever this jax accepts
-    check_kw = (
-        "check_vma"
-        if "check_vma" in inspect.signature(shard_map).parameters
-        else "check_rep"
-    )
-
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(pipeline_spec_for(params_stacked), x_spec),
         out_specs=x_spec,
-        **{check_kw: False},
+        check_vma=False,
     )
     def run(stage_params, x_local):
         p_local = jax.tree_util.tree_map(lambda a: a[0], stage_params)

@@ -44,7 +44,7 @@ def test_param_shardings_place_leaves_on_mesh():
         from repro.configs import ARCHITECTURES, reduce_config
         from repro.models.transformer import build_model
         from repro.runtime import param_shardings, shard_params
-        from repro.launch.mesh import make_local_mesh, use_mesh
+        from repro.launch.mesh import make_local_mesh
 
         mesh = make_local_mesh(data=2, model=4)
         # widen the reduced config so dims divide the mesh axes
@@ -59,7 +59,7 @@ def test_param_shardings_place_leaves_on_mesh():
         # forward still works on sharded params
         batch = {'tokens': jax.numpy.zeros((4, 8), jax.numpy.int32),
                  'labels': jax.numpy.zeros((4, 8), jax.numpy.int32)}
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             loss, _ = jax.jit(model.train_loss)(sharded, batch)
         assert bool(jax.numpy.isfinite(loss))
         print('OK')
@@ -76,7 +76,7 @@ def test_pjit_train_step_multidevice_matches_single_device():
         from repro.train import AdamWConfig, TrainConfig, init_train_state, make_train_step
         from repro.data import DataConfig, SyntheticLMDataset
         from repro.runtime import shard_params
-        from repro.launch.mesh import make_local_mesh, use_mesh
+        from repro.launch.mesh import make_local_mesh
 
         cfg = reduce_config(ARCHITECTURES['qwen2-7b'], d_model=64, n_heads=4,
                             n_kv_heads=4, head_dim=16, d_ff=128, vocab_size=256)
@@ -95,7 +95,7 @@ def test_pjit_train_step_multidevice_matches_single_device():
 
         # sharded result on the 2×4 mesh
         mesh = make_local_mesh(data=2, model=4)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             sp = shard_params(params, mesh)
             st2 = init_train_state(sp, tcfg)
             p2, o2, _, m2 = jax.jit(step)(st2.params, st2.opt_state, None,
@@ -118,7 +118,7 @@ def test_pipeline_apply_matches_sequential():
         """
         import jax, jax.numpy as jnp, numpy as np
         from repro.runtime.pipeline import pipeline_apply, stack_stage_params
-        from repro.launch.mesh import _mk, use_mesh
+        from repro.launch.mesh import _mk
 
         mesh = _mk((2, 4), ('pod', 'data'))
         L, d = 8, 16
@@ -135,7 +135,7 @@ def test_pipeline_apply_matches_sequential():
         for i in range(L):
             ref = jnp.tanh(ref @ w[i])
         stacked = stack_stage_params({'w': w}, 2)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             for n_micro in (1, 2, 4):
                 out = pipeline_apply(stage_fn, stacked, x, mesh=mesh, n_micro=n_micro)
                 err = float(jnp.abs(out - ref).max())
@@ -150,11 +150,11 @@ def test_multipod_mesh_cross_pod_collectives():
         """
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
-        from repro.launch.mesh import _mk, use_mesh
+        from repro.launch.mesh import _mk
 
         mesh = _mk((2, 2, 2), ('pod', 'data', 'model'))
         x = jnp.arange(16.0).reshape(8, 2)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             xs = jax.device_put(x, NamedSharding(mesh, P(('pod', 'data'), 'model')))
             total = jax.jit(lambda a: a.sum())(xs)
         assert float(total) == float(x.sum())
@@ -172,7 +172,7 @@ def test_checkpoint_restore_onto_different_mesh():
         from repro.configs import ARCHITECTURES, reduce_config
         from repro.models.transformer import build_model
         from repro.runtime import param_shardings, shard_params
-        from repro.launch.mesh import make_local_mesh, use_mesh
+        from repro.launch.mesh import make_local_mesh
 
         cfg = reduce_config(ARCHITECTURES['qwen2-7b'], d_model=64, n_heads=4,
                             n_kv_heads=4, head_dim=16, d_ff=128, vocab_size=256)

@@ -58,6 +58,7 @@ def _start_server(tmp: pathlib.Path, *, kill_at_tick=None,
         "--snapshot-dir", str(tmp / "snaps"),
         "--snapshot-every", str(snapshot_every),
         "--nodes", str(NODES), "--seed", str(SEED),
+        "--backend", "reference",
     ]
     if kill_at_tick is not None:
         cmd += ["--kill-at-tick", str(kill_at_tick)]

@@ -115,7 +115,7 @@ def test_model_flops_convention():
 def test_build_cell_shapes_are_allocation_free():
     """build_cell must work purely in eval_shape land."""
     from repro.configs import SHAPES, get_config
-    from repro.launch.mesh import make_local_mesh, use_mesh
+    from repro.launch.mesh import make_local_mesh
     from repro.launch.specs import build_cell
 
     mesh = make_local_mesh(model=1)
@@ -136,14 +136,14 @@ def test_reduced_cell_lowers_and_compiles_on_local_mesh():
     import dataclasses
 
     from repro.configs import SHAPES, get_config
-    from repro.launch.mesh import make_local_mesh, use_mesh
+    from repro.launch.mesh import make_local_mesh
     from repro.launch.specs import build_cell
 
     mesh = make_local_mesh(model=1)
     cfg = reduce_config(get_config("qwen3-32b"))
     shape = dataclasses.replace(SHAPES["train_4k"], seq_len=32, global_batch=4)
     cell = build_cell(cfg, shape, mesh)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(
             cell.step_fn,
             in_shardings=cell.in_shardings,

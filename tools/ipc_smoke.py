@@ -130,6 +130,8 @@ def coordinator(args) -> int:
             raise RuntimeError("server exited before READY")
 
         per_client = args.users // args.clients
+        # clients do host work only; the server is the one device owner
+        worker_env = dict(env, JAX_PLATFORMS="cpu")
         workers = [
             subprocess.Popen(
                 [
@@ -143,7 +145,7 @@ def coordinator(args) -> int:
                     "--name", f"smoke{i}",
                     "--traffic-seed", str(100 + i),
                 ],
-                env=env,
+                env=worker_env,
             )
             for i in range(args.clients)
         ]
