@@ -753,8 +753,6 @@ class SolverServer:
                 "solved": report.solved,
                 "dispatches": report.dispatches,
                 "queue_depth": report.queue_depth,
-                "batch_hits": report.batch_hits,
-                "batch_solved": report.batch_solved,
                 "faults": report.faults,
                 "retries": report.retries,
                 "breaker_trips": report.breaker_trips,
