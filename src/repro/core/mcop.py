@@ -72,6 +72,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.graph import WCG, WCGBatch
+from repro.obs.trace import NULL_SPAN
 
 __all__ = [
     "PhaseRecord",
@@ -502,6 +503,14 @@ def _dispatch_arrays(adj, wl, wc, pin, backend: str, interpret: bool | None):
     return mcop_stoer_wagner_kernel(adj, wl, wc, pin, interpret=interpret)
 
 
+def _solve_wait(tracer):
+    """Span over the host's wait for a flush's device results
+    (``solve.wait``, a child of the caller's ``stage.solve_flush``): it
+    ends once the results are on the host, after the solve program has
+    ended on the device."""
+    return tracer.span("solve.wait") if tracer is not None else NULL_SPAN
+
+
 def _solve_wcg_batch(
     batch: WCGBatch,
     *,
@@ -541,7 +550,8 @@ def _solve_wcg_batch(
             backend,
             interpret,
         )
-        cuts, masks = jax.device_get((cuts, masks))  # one host sync
+        with _solve_wait(tracer):
+            cuts, masks = jax.device_get((cuts, masks))  # one host sync
     return [
         MCOPResult(
             min_cut=float(cuts[i]),
@@ -583,8 +593,9 @@ def mcop_batch(
         sees when there is more than one, ``False`` forces the
         single-device dispatch, a ``Mesh`` shards over exactly that
         fleet.  Results are bit-identical either way.
-      tracer:   optional :class:`~repro.obs.trace.Tracer` — the sharded
-        path records one ``solve.shard`` span per device (shard index,
+      tracer:   optional :class:`~repro.obs.trace.Tracer` — the wait for
+        each bucket's results is a ``solve.wait`` span, and on the sharded
+        path it holds one ``solve.shard`` span per device (shard index,
         device count, row count).
     Returns:
       ``list[MCOPResult]`` in input order; ``result[i].local_mask`` is
@@ -633,7 +644,8 @@ def mcop_batch(
         else:
             adj, wl, wc, pin = (jnp.asarray(a) for a in packed)
             cuts, masks = _dispatch_arrays(adj, wl, wc, pin, backend, interpret)
-            cuts, masks = jax.device_get((cuts, masks))  # one host sync
+            with _solve_wait(tracer):
+                cuts, masks = jax.device_get((cuts, masks))  # one host sync
         for row, i in enumerate(idxs):
             results[i] = MCOPResult(
                 min_cut=float(cuts[row]),
@@ -759,8 +771,9 @@ def solve_envs(
         process sees when there is more than one, ``False`` forces the
         single-device program, a ``Mesh`` shards over exactly that
         fleet.  Sharded results are bit-identical to unsharded.
-      tracer:  optional :class:`~repro.obs.trace.Tracer` — the sharded
-        path records one ``solve_envs.shard`` span per device.
+      tracer:  optional :class:`~repro.obs.trace.Tracer` — the wait for
+        the results is a ``solve.wait`` span, holding one
+        ``solve_envs.shard`` span per device on the sharded path.
     Returns:
       ``list[MCOPResult]``, one per environment in input order, masks
       ``(n,)`` bool over the profile's vertices.
@@ -803,7 +816,7 @@ def solve_envs(
             backend=backend, bucket=bucket, devices=devices,
         )
     else:
-        from repro.obs.trace import NULL_SPAN as timer
+        timer = NULL_SPAN
     if backend == "reference":
         with timer:
             return [
@@ -858,7 +871,8 @@ def solve_envs(
                 jnp.asarray(pinned),
                 env_cols,
             )
-            cuts, masks = jax.device_get((cuts, masks))  # one host sync
+            with _solve_wait(tracer):
+                cuts, masks = jax.device_get((cuts, masks))  # one host sync
     return [
         MCOPResult(min_cut=float(cuts[i]), local_mask=masks[i, :n].copy(), phases=[])
         for i in range(k)

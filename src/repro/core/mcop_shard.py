@@ -231,8 +231,11 @@ def sharded_dispatch_arrays(
     cuts_sh, masks_sh = fn(
         adj[plan.perm], wl[plan.perm], wc[plan.perm], pin[plan.perm]
     )
-    _emit_shard_spans(tracer, plan, (cuts_sh, masks_sh), stage="solve")
-    cuts_sh, masks_sh = jax.device_get((cuts_sh, masks_sh))
+    from repro.core.mcop import _solve_wait  # deferred: cycle
+
+    with _solve_wait(tracer):
+        _emit_shard_spans(tracer, plan, (cuts_sh, masks_sh), stage="solve")
+        cuts_sh, masks_sh = jax.device_get((cuts_sh, masks_sh))
     return cuts_sh[plan.inverse][: plan.k], masks_sh[plan.inverse][: plan.k]
 
 
@@ -299,6 +302,9 @@ def sharded_solve_envs_call(
     cols = [c[plan.perm] for c in cols]
     env_sh = type(env_arrays)(*cols)
     cuts_sh, masks_sh = fn(t_local, data_in, data_out, pinned, env_sh)
-    _emit_shard_spans(tracer, plan, (cuts_sh, masks_sh), stage="solve_envs")
-    cuts_sh, masks_sh = jax.device_get((cuts_sh, masks_sh))
+    from repro.core.mcop import _solve_wait  # deferred: cycle
+
+    with _solve_wait(tracer):
+        _emit_shard_spans(tracer, plan, (cuts_sh, masks_sh), stage="solve_envs")
+        cuts_sh, masks_sh = jax.device_get((cuts_sh, masks_sh))
     return cuts_sh[plan.inverse][: plan.k], masks_sh[plan.inverse][: plan.k]

@@ -28,7 +28,6 @@ asserts ``==``, not ``approx``.
 from __future__ import annotations
 
 import dataclasses
-from collections import OrderedDict
 from typing import Any, Sequence
 
 import jax
@@ -41,7 +40,6 @@ __all__ = [
     "price_batch",
     "price_trace",
     "vector_gain",
-    "device_price_summary",
 ]
 
 
@@ -162,126 +160,3 @@ def price_trace(
     batch = model.build_batch(profile, envs)  # unpadded: m == profile.n
     return price_batch(batch, masks)
 
-
-# ----------------------------------------------------------------------
-# Device-resident reduction: build → price → reduce inside ONE jitted
-# program, so telemetry over K sessions syncs a handful of scalars to
-# the host instead of K-sized report arrays.
-# ----------------------------------------------------------------------
-
-# Compiled build+price+reduce programs, keyed like mcop._FUSED_SOLVERS:
-# equal-fingerprint models price identically (CostModel.fingerprint
-# contract), and jit re-specializes per input shape, so (type,
-# fingerprint) suffices.  LRU-bounded for parametric-model sweeps.
-_DEVICE_PRICERS: OrderedDict = OrderedDict()
-_DEVICE_PRICERS_CAP = 64
-
-_SUMMARY_FIELDS = (
-    "partial_mean",
-    "partial_min",
-    "partial_max",
-    "no_offload_mean",
-    "full_offload_mean",
-    "gain_mean",
-    "gain_min",
-    "gain_max",
-)
-
-
-def _device_pricer(model):
-    import jax.numpy as jnp
-
-    key = (type(model), model.fingerprint)
-    fn = _DEVICE_PRICERS.get(key)
-    if fn is not None:
-        _DEVICE_PRICERS.move_to_end(key)
-        return fn
-
-    def fused(t_local, data_in, data_out, offloadable, env, masks, weights):
-        wl, wc, adj = model.batch_weights(t_local, data_in, data_out, env)
-
-        def price(m):
-            node = jnp.where(m, wl, wc).sum(axis=-1)
-            cut = m[:, :, None] != m[:, None, :]
-            return node + (adj * cut).sum(axis=(-1, -2)) / 2.0
-
-        partial = price(masks)
-        no_off = wl.sum(axis=-1)
-        full = price(jnp.broadcast_to(~offloadable[None, :], masks.shape))
-        gain = jnp.where(no_off > 0, 1.0 - partial / no_off, 0.0)
-        # weighted (active-session) reductions; `weights` is 0/1 so idle
-        # slots of a fixed-capacity session batch never skew the means
-        w_sum = jnp.maximum(weights.sum(), 1.0)
-
-        def masked_min(x):
-            return jnp.where(weights > 0, x, jnp.inf).min()
-
-        def masked_max(x):
-            return jnp.where(weights > 0, x, -jnp.inf).max()
-
-        return {
-            "partial_mean": (partial * weights).sum() / w_sum,
-            "partial_min": masked_min(partial),
-            "partial_max": masked_max(partial),
-            "no_offload_mean": (no_off * weights).sum() / w_sum,
-            "full_offload_mean": (full * weights).sum() / w_sum,
-            "gain_mean": (gain * weights).sum() / w_sum,
-            "gain_min": masked_min(gain),
-            "gain_max": masked_max(gain),
-        }
-
-    fn = _DEVICE_PRICERS[key] = jax.jit(fused)
-    while len(_DEVICE_PRICERS) > _DEVICE_PRICERS_CAP:
-        _DEVICE_PRICERS.popitem(last=False)
-    return fn
-
-
-def device_price_summary(profile, model, envs, masks, active=None) -> dict:
-    """Fused device-side pricing telemetry: K sessions → ~8 scalars.
-
-    The whole chain — ``model.batch_weights`` WCG construction, Eq.-2
-    pricing of the placements, both §7.1 baselines, the offloading gains
-    *and the reductions over sessions* — runs inside one jitted XLA
-    program; only the reduced scalars cross the host boundary.  This is
-    the dashboard path for batched session ticks at 10⁵–10⁶ users, where
-    syncing K-sized :class:`PriceReport` arrays per tick would dominate.
-
-    Args:
-      profile: shared :class:`~repro.core.cost_models.AppProfile`.
-      model:   :class:`~repro.core.cost_models.CostModel` objective.
-      envs:    :class:`~repro.core.cost_models.EnvArrays` (k rows) or a
-               sequence of Environments.
-      masks:   (k, n) bool placements to price.
-      active:  optional (k,) bool — sessions to include in the
-               reductions (idle slots of a fixed-capacity batch are
-               priced but excluded).
-    Returns:
-      dict of Python floats (mean/min/max partial cost, mean baselines,
-      mean/min/max gain) in device precision — f32 unless jax x64 is
-      enabled, so this is telemetry, NOT the bit-exact host pricing path
-      that placement/clamp decisions ride.
-    """
-    import jax.numpy as jnp
-
-    from repro.core.cost_models import EnvArrays
-
-    if not isinstance(envs, EnvArrays):
-        envs = EnvArrays.from_envs(envs)
-    masks = np.asarray(masks, dtype=bool)
-    weights = (
-        np.ones(masks.shape[0])
-        if active is None
-        else np.asarray(active, dtype=np.float64)
-    )
-    fn = _device_pricer(model)
-    out = fn(
-        jnp.asarray(np.asarray(profile.t_local)),
-        jnp.asarray(np.asarray(profile.data_in)),
-        jnp.asarray(np.asarray(profile.data_out)),
-        jnp.asarray(profile.offloadable),
-        jax.tree_util.tree_map(jnp.asarray, envs),
-        jnp.asarray(masks),
-        jnp.asarray(weights),
-    )
-    out = jax.device_get(out)  # ONE host sync for the whole summary
-    return {k: float(out[k]) for k in _SUMMARY_FIELDS}

@@ -299,7 +299,6 @@ class SessionTickReport:
     solved: int                  # representative solves dispatched
     coalesced: int               # same-bin followers folded into a solve
     due: int                     # sessions repartitioned this tick
-    device_summary: dict | None = None  # fused device telemetry (optional)
     # fault-tolerance (resilient ticks only; see tick_sessions(faults=))
     degraded: np.ndarray | None = None  # (k,) bool — rows served a fallback
     retries: int = 0             # solve-flush retries performed this tick
@@ -362,7 +361,6 @@ def tick_sessions(
     cache: PlacementCache,
     backend: str = "jax",
     buckets: Sequence[int] = DEFAULT_BUCKETS,
-    device_telemetry: bool = False,
     faults=None,
     resilience=None,
     tick: int = 0,
@@ -756,7 +754,7 @@ def tick_sessions(
     cache_hit = np.zeros(batch.capacity, dtype=bool)
     cache_hit[hit_idx] = True
     cache_hit[fol_idx] = True
-    tick_report = SessionTickReport(
+    return SessionTickReport(
         steps=batch.steps.copy(),
         active=batch.active.copy(),
         repartitioned=due,
@@ -777,8 +775,3 @@ def tick_sessions(
         faults=n_faults,
         breaker_trips=n_trips,
     )
-    if device_telemetry:
-        tick_report.device_summary = pricing.device_price_summary(
-            profile, model, envs, rows, active=batch.active
-        )
-    return tick_report

@@ -22,6 +22,11 @@ degraded reply come from**:
   format ``tools/tracequery.py`` consumes) and
   :meth:`Tracer.export_chrome` (Chrome ``trace_event`` JSON: load it in
   ``about://tracing`` / Perfetto for a flame view of broker ticks).
+* **Profiler timeline** — while enabled, every span also enters and
+  exits an annotation (by default ``jax.profiler.TraceAnnotation`` with
+  the span's name and opening attributes, imported on first use), so a
+  profiler trace taken with host tracing on shows the server's spans
+  beside the device's ops, on the profiler's one clock.
 
 With no tracer attached the instrumented paths never construct a span
 (the broker's helpers return the shared :data:`NULL_SPAN`), so detached
@@ -35,9 +40,9 @@ import json
 import pathlib
 import time
 from collections import deque
-from typing import Callable
+from typing import Any, Callable
 
-__all__ = ["Span", "Tracer", "NULL_SPAN"]
+__all__ = ["Span", "Tracer", "NULL_SPAN", "profiler_annotation"]
 
 
 class _NullSpan:
@@ -60,6 +65,23 @@ class _NullSpan:
 
 NULL_SPAN = _NullSpan()
 
+_TRACE_ANNOTATION = None
+
+
+def profiler_annotation(name: str, **attrs):
+    """``jax.profiler.TraceAnnotation(name, **attrs)``, imported on first
+    use so that ``repro.obs`` stays importable without JAX (where JAX is
+    missing, a no-op context)."""
+    global _TRACE_ANNOTATION
+    if _TRACE_ANNOTATION is None:
+        try:
+            from jax.profiler import TraceAnnotation as annotation
+        except ImportError:
+            def annotation(name, **attrs):  # noqa: ARG001
+                return NULL_SPAN
+        _TRACE_ANNOTATION = annotation
+    return _TRACE_ANNOTATION(name, **attrs)
+
 
 class Span:
     """One timed region.  Created by :meth:`Tracer.span`; use as a
@@ -76,6 +98,7 @@ class Span:
         "t0",
         "t1",
         "_tracer",
+        "_annotation",
     )
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict):
@@ -87,8 +110,11 @@ class Span:
         self.t0 = 0.0
         self.t1 = 0.0
         self._tracer = tracer
+        self._annotation = None
 
     def __enter__(self) -> "Span":
+        self._annotation = self._tracer.annotation(self.name, **self.attrs)
+        self._annotation.__enter__()
         self._tracer._push(self)
         return self
 
@@ -96,6 +122,7 @@ class Span:
         if exc_type is not None:
             self.attrs.setdefault("error", exc_type.__name__)
         self._tracer._pop(self)
+        self._annotation.__exit__(exc_type, exc, tb)
         return False
 
     def set(self, **attrs) -> None:
@@ -134,6 +161,10 @@ class Tracer:
       enabled:  ``False`` makes :meth:`span` return :data:`NULL_SPAN`
                 and :meth:`event` a no-op (the zero-cost switch; flip
                 at runtime to start/stop capturing).
+      annotation: ``(name, **attrs) -> context manager`` entered around
+                every span (outside its timestamps) — default
+                :func:`profiler_annotation`, which puts the span on the
+                profiler's timeline.
     """
 
     def __init__(
@@ -142,11 +173,13 @@ class Tracer:
         clock: Callable[[], float] = time.perf_counter,
         capacity: int = 4096,
         enabled: bool = True,
+        annotation: Callable[..., Any] = profiler_annotation,
     ):
         if capacity <= 0:
             raise ValueError("capacity must be positive")
         self.clock = clock
         self.enabled = bool(enabled)
+        self.annotation = annotation
         self._ring: deque[Span] = deque(maxlen=int(capacity))
         self._stack: list[Span] = []
         self._next_id = 1

@@ -409,6 +409,10 @@ class _Request:
     env: Environment | None = None
     lane: str = "user"
     expires: int | None = None  # absolute tick deadline (None = no deadline)
+    # traced brokers only: the request's id and the tracer-clock time the
+    # scheduler accepted it (kept when the request is put back)
+    rid: object = None
+    queued_at: float | None = None
 
     @property
     def n(self) -> int:
@@ -471,6 +475,10 @@ class OffloadBroker:
                 tags fault/retry/breaker/degraded/timed-out events onto
                 the active span, so a degraded reply in an exported
                 trace is attributable to the exact injected fault.
+                Each request is stamped with the tracer's clock as the
+                scheduler accepts it, and ``broker.tick`` records the
+                id (``request_ids``) and queue wait (``queue_wait_s``)
+                of every request it drains.
       metrics:  optional :class:`~repro.obs.metrics.MetricsRegistry` —
                 telemetry counters mirror into it
                 (:meth:`BrokerTelemetry.bind_metrics`), tick latency
@@ -528,6 +536,7 @@ class OffloadBroker:
         self._rejected_since_tick = 0
         self._deadlines_armed = False
         self._tick = 0
+        self._traced_requests = 0  # ids for traced submits that bring none
 
     # -- tenants ---------------------------------------------------------
     def register(
@@ -593,7 +602,6 @@ class OffloadBroker:
         *,
         threshold: float = 0.10,
         min_interval: int = 1,
-        device_telemetry: bool = False,
     ):
         """Attach a :class:`~repro.service.session.BatchSessionGroup`.
 
@@ -621,7 +629,6 @@ class OffloadBroker:
             capacity=capacity,
             threshold=threshold,
             min_interval=min_interval,
-            device_telemetry=device_telemetry,
         )
         self._batch_groups.append(group)
         return group
@@ -675,6 +682,11 @@ class OffloadBroker:
         admitted = self._scheduler.submit(
             QueueEntry(r.tenant.name, r, (r.n, r.key), lane=r.lane)
         )
+        if admitted and self.tracer is not None and self.tracer.enabled:
+            if r.rid is None:
+                self._traced_requests += 1
+                r.rid = self._traced_requests
+            r.queued_at = self.tracer.clock()
         if not admitted:
             self._rejected_since_tick += 1
             self._event(
@@ -712,6 +724,7 @@ class OffloadBroker:
         *,
         lane: str = "user",
         deadline: int | None = None,
+        request_id=None,
     ) -> PlacementFuture:
         """Enqueue a solve for ``env`` under the tenant's cost model.
 
@@ -725,6 +738,9 @@ class OffloadBroker:
                 still queued after that many ticks resolves as
                 ``timed_out`` (default: the resilience policy's
                 ``deadline_ticks``, or no deadline).
+          request_id: the caller's id for the request (the wire's
+                submit id), recorded by ``broker.tick`` when a tracer is
+                attached; traced requests without one are numbered.
         Returns:
           :class:`PlacementFuture`, resolved by a later :meth:`tick` —
           or immediately with a ``rejected`` reply when the scheduler's
@@ -749,6 +765,7 @@ class OffloadBroker:
                 env=env,
                 lane=lane,
                 expires=self._deadline_tick(deadline),
+                rid=request_id,
             )
         )
 
@@ -760,12 +777,13 @@ class OffloadBroker:
         *,
         lane: str = "user",
         deadline: int | None = None,
+        request_id=None,
     ) -> PlacementFuture:
         """Enqueue a caller-built WCG; ``env`` only determines the bin key.
 
-        Same future/rejection/deadline semantics as :meth:`submit`; used
-        by raw-graph tenants (elastic manager, broker sessions carrying
-        an already-built controller graph).
+        Same future/rejection/deadline/request-id semantics as
+        :meth:`submit`; used by raw-graph tenants (elastic manager,
+        broker sessions carrying an already-built controller graph).
         """
         t = self._tenants[name]
         return self._enqueue(
@@ -777,6 +795,7 @@ class OffloadBroker:
                 env=env,
                 lane=lane,
                 expires=self._deadline_tick(deadline),
+                rid=request_id,
             )
         )
 
@@ -851,6 +870,13 @@ class OffloadBroker:
             depth = self._scheduler.pending
             entries = self._scheduler.drain(budget)
             requests = [e.item for e in entries]
+            if self.tracer is not None and self.tracer.enabled:
+                now = self.tracer.clock()
+                stamped = [r for r in requests if r.queued_at is not None]
+                root.set(
+                    request_ids=[r.rid for r in stamped],
+                    queue_wait_s=[now - r.queued_at for r in stamped],
+                )
             ctx = (
                 _TickCtx(
                     self.fault_injector,

@@ -39,7 +39,10 @@ concurrently — the determinism that makes cross-process replies
 ``==``-identical to an in-process broker fed the same submission order.
 
 Observability: per-frame spans (``wire.frame`` with ``transport`` and
-frame-type labels) nest the broker's own tick spans, and wire traffic
+frame-type labels, and a submit's request ``id``) nest the broker's own
+tick spans; ``server.wait`` covers the reactor blocked in ``select`` and
+``wire.read`` each receive and frame decode, so with a tracer attached
+the server's top-level spans account for its whole time.  Wire traffic
 feeds ``wire_frames`` / ``wire_bytes`` counters plus a
 ``wire_frame_handle_s`` histogram when a
 :class:`~repro.obs.metrics.MetricsRegistry` is attached.
@@ -385,6 +388,7 @@ class SolverServer:
                         wire_to_env(e["env"]),
                         lane=e.get("lane", "user"),
                         deadline=e.get("deadline"),
+                        request_id=rid,
                     )
                 except Exception:
                     continue  # tenant no longer registered: drop the entry
@@ -473,7 +477,9 @@ class SolverServer:
         self._running = True
         try:
             while self._running:
-                for key, mask in self._sel.select(poll_s):
+                with self._span("server.wait"):
+                    ready = self._sel.select(poll_s)
+                for key, mask in ready:
                     if key.data is None:
                         self._accept()
                     else:
@@ -571,32 +577,43 @@ class SolverServer:
             self._flush_outbox(conn)
 
     def _on_readable(self, conn: _Conn) -> None:
-        try:
-            chunk = conn.sock.recv(65536)
-        except BlockingIOError:
-            return
-        except OSError:
-            self._close_conn(conn)
-            return
-        if not chunk:
-            self._close_conn(conn)
-            return
-        conn.buf.extend(chunk)
-        while True:
+        # each wire.read span closes before the frame it decoded is
+        # handled, so it never holds a wire.frame span
+        with self._span("wire.read", transport=self.transport):
+            got = self._read_frame(conn, receive=True)
+        while got is not None:
+            self._handle_frame(conn, *got)
+            if conn.closing or not conn.buf:
+                return
+            with self._span("wire.read", transport=self.transport):
+                got = self._read_frame(conn, receive=False)
+
+    def _read_frame(self, conn: _Conn, *, receive: bool):
+        """Receive once (if asked), then decode the next whole frame of
+        the connection's buffer: ``(frame, nbytes)``, or ``None`` when
+        more bytes are needed or the connection is closing."""
+        if receive:
             try:
-                frame, used = decode_frame(
-                    bytes(conn.buf), max_frame=self.max_frame
-                )
-            except TruncatedFrame:
-                return  # wait for more bytes
-            except (FrameTooLarge, BadFrame) as err:
-                # the length prefix cannot be trusted: no resync possible
-                self._fail(conn, err.code, str(err), close=True)
-                return
-            del conn.buf[:used]
-            self._handle_frame(conn, frame, used)
-            if conn.closing:
-                return
+                chunk = conn.sock.recv(65536)
+            except BlockingIOError:
+                return None
+            except OSError:
+                self._close_conn(conn)
+                return None
+            if not chunk:
+                self._close_conn(conn)
+                return None
+            conn.buf.extend(chunk)
+        try:
+            frame, used = decode_frame(bytes(conn.buf), max_frame=self.max_frame)
+        except TruncatedFrame:
+            return None  # wait for more bytes
+        except (FrameTooLarge, BadFrame) as err:
+            # the length prefix cannot be trusted: no resync possible
+            self._fail(conn, err.code, str(err), close=True)
+            return None
+        del conn.buf[:used]
+        return frame, used
 
     # -- frame dispatch --------------------------------------------------
     def _handle_frame(self, conn: _Conn, frame: dict, nbytes: int) -> None:
@@ -609,10 +626,10 @@ class SolverServer:
             if self.metrics is not None
             else NULL_SPAN
         )
-        with timer, self._span(
-            "wire.frame", type=ftype, transport=self.transport,
-            client=conn.name,
-        ):
+        attrs = {"type": ftype, "transport": self.transport, "client": conn.name}
+        if "id" in frame:
+            attrs["id"] = frame["id"]  # the broker's spans carry it too
+        with timer, self._span("wire.frame", **attrs):
             if not conn.ready:
                 if ftype == "hello":
                     self._on_hello(conn, frame)
@@ -718,7 +735,9 @@ class SolverServer:
                     "deadline": deadline,
                 }
             )
-        fut = self.broker.submit(tenant, env, lane=lane, deadline=deadline)
+        fut = self.broker.submit(
+            tenant, env, lane=lane, deadline=deadline, request_id=rid
+        )
         if fut.done:  # immediate backpressure rejection
             self._replies[rid] = reply_to_wire(fut.result)
             self._send(conn, {"type": "reply", "id": rid,

@@ -167,14 +167,12 @@ class BatchSessionGroup:
         capacity: int,
         threshold: float = 0.10,
         min_interval: int = 1,
-        device_telemetry: bool = False,
     ):
         t = broker.tenant(tenant)
         if t.profile is None:
             raise ValueError(f"tenant {tenant!r} has no profile/cost model")
         self.broker = broker
         self.tenant = tenant
-        self.device_telemetry = device_telemetry
         self.batch = SessionBatch.create(
             capacity,
             t.profile.n,
@@ -239,7 +237,6 @@ class BatchSessionGroup:
             cache=t.cache,
             backend=self.broker.backend,
             buckets=self.broker.buckets,
-            device_telemetry=self.device_telemetry,
             faults=self.broker.fault_injector,
             resilience=self.broker.resilience,
             tick=self.broker._tick,

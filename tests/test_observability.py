@@ -22,6 +22,7 @@ import dataclasses
 import importlib.util
 import json
 import pathlib
+import threading
 
 import numpy as np
 import pytest
@@ -40,13 +41,16 @@ from repro.obs.metrics import (
     NULL_HISTOGRAM,
     Histogram,
 )
-from repro.obs.trace import NULL_SPAN
+from repro.obs.trace import NULL_SPAN, profiler_annotation
 from repro.service import (
+    BrokerClient,
     CircuitBreaker,
     FaultInjector,
     InjectedClock,
     ScriptedFaultInjector,
+    SolverServer,
     run_workload,
+    unix_address,
     user_traces,
 )
 from tests.test_faults import (
@@ -589,3 +593,222 @@ def test_chaos_trace_tool_is_deterministic(tmp_path):
     # and the artifact passes the same audit CI runs
     spans = tracequery.load_spans(paths[0])
     assert spans and tracequery.audit(spans) == []
+
+
+# ----------------------------------------------------------------------
+# Profiler annotations, server reactor spans, queue wait, solve wait
+# ----------------------------------------------------------------------
+
+
+class _Annotation:
+    """Annotation factory that logs enters and exits in order."""
+
+    log: list = []
+
+    def __init__(self, name, **attrs):
+        self.name, self.attrs = name, attrs
+
+    def __enter__(self):
+        self.log.append(("enter", self.name, self.attrs))
+        return self
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.name))
+        return False
+
+
+def test_tracer_opens_one_annotation_per_span_in_nesting_order():
+    _Annotation.log = []
+    tr = Tracer(clock=InjectedClock(), annotation=_Annotation)
+    with tr.span("broker.tick", tick=3):
+        with tr.span("stage.solve_flush", bucket=16):
+            with tr.span("solve.wait"):
+                pass
+        with pytest.raises(ValueError):
+            with tr.span("stage.commit"):
+                raise ValueError("boom")
+    assert _Annotation.log == [
+        ("enter", "broker.tick", {"tick": 3}),
+        ("enter", "stage.solve_flush", {"bucket": 16}),
+        ("enter", "solve.wait", {}),
+        ("exit", "solve.wait"),
+        ("exit", "stage.solve_flush"),
+        ("enter", "stage.commit", {}),
+        ("exit", "stage.commit"),
+        ("exit", "broker.tick"),
+    ]
+    assert len(tr) == 4
+    # a disabled tracer opens none; the default is the profiler's own
+    _Annotation.log = []
+    assert Tracer(enabled=False, annotation=_Annotation).span("x") is NULL_SPAN
+    assert _Annotation.log == []
+    import jax
+
+    assert isinstance(profiler_annotation("wire.read"), jax.profiler.TraceAnnotation)
+
+
+def _serve(tmp_path, broker, tracer=None):
+    server = SolverServer(
+        broker, address=unix_address(tmp_path / "srv.sock"), tracer=tracer
+    )
+    server.bind()
+    thread = threading.Thread(
+        target=server.serve_forever, kwargs={"poll_s": 0.01}, daemon=True
+    )
+    thread.start()
+    return server, thread
+
+
+def _submit_and_tick(server, profile, envs, ticks=1):
+    client = BrokerClient(
+        unix_address(server.address[1]),
+        tenants={"app": (profile, ResponseTimeModel())},
+        client="obs", timeout=10.0,
+    ).connect()
+    futures = [client.submit("app", env) for env in envs]
+    for _ in range(ticks):
+        client.tick()
+    assert all(f.done for f in futures)
+    client.close()
+    return futures
+
+
+def test_detached_broker_and_server_make_no_span_and_stamp_nothing(
+    tmp_path, monkeypatch
+):
+    import repro.obs.trace as trace_mod
+    import repro.service.broker as broker_mod
+
+    made = []
+    monkeypatch.setattr(
+        trace_mod.Span, "__init__",
+        lambda self, *a, **k: made.append(a) or None,
+    )
+    queued = []
+    enqueue = broker_mod.OffloadBroker._enqueue
+    monkeypatch.setattr(
+        broker_mod.OffloadBroker, "_enqueue",
+        lambda self, r: queued.append(r) or enqueue(self, r),
+    )
+    reads = []
+    clock = InjectedClock()
+    broker = _broker(clock=lambda: reads.append(1) or clock())
+    profile = _profile(8, 1)
+    broker.register("app", profile, ResponseTimeModel())
+    server, thread = _serve(tmp_path, broker)
+    try:
+        _submit_and_tick(server, profile, [_env(0.5 + i) for i in range(3)], ticks=2)
+    finally:
+        server.stop()
+        thread.join(timeout=10)
+    assert made == []
+    assert len(queued) == 3
+    assert all(r.queued_at is None for r in queued)
+    assert len(reads) == 2 * broker._tick == 4
+
+
+def test_broker_tick_records_request_ids_and_queue_waits():
+    clock = InjectedClock()
+    tr = Tracer(clock=clock)
+    broker = _broker(tracer=tr)
+    broker.register("app", _profile(8, 2), ResponseTimeModel())
+    broker.submit("app", _env(0.5), request_id="a")
+    clock.advance(1.0)
+    broker.submit("app", _env(3.0), request_id="b")
+    broker.submit("app", _env(9.0))  # no id given: the broker numbers it
+    clock.advance(0.5)
+    broker.tick(budget=2)
+    clock.advance(0.25)
+    broker.tick()
+    first, second = tr.spans("broker.tick")
+    assert first.attrs["request_ids"] == ["a", "b"]
+    assert first.attrs["queue_wait_s"] == [1.5, 0.5]
+    assert second.attrs["request_ids"] == [1]
+    assert second.attrs["queue_wait_s"] == [0.75]
+
+
+def test_requeued_request_keeps_its_first_stamp(monkeypatch):
+    import repro.service.broker as broker_mod
+
+    clock = InjectedClock()
+    tr = Tracer(clock=clock)
+    broker = _broker(tracer=tr)
+    broker.register("app", _profile(8, 3), ResponseTimeModel())
+    fut = broker.submit("app", _env(2.0), request_id="r1")
+    clock.advance(1.0)
+    solve = broker_mod.mcop_batch
+
+    def fail(*a, **k):
+        raise RuntimeError("device lost")
+
+    monkeypatch.setattr(broker_mod, "mcop_batch", fail)
+    with pytest.raises(RuntimeError):
+        broker.tick()
+    assert broker.pending == 1 and not fut.done
+    monkeypatch.setattr(broker_mod, "mcop_batch", solve)
+    clock.advance(2.0)
+    broker.tick()
+    assert fut.done
+    failed, served = tr.spans("broker.tick")
+    assert failed.attrs["queue_wait_s"] == [1.0]
+    assert served.attrs["request_ids"] == ["r1"]
+    assert served.attrs["queue_wait_s"] == [3.0]
+
+
+def _parent(spans, span):
+    by_id = {s.span_id: s for s in spans}
+    return by_id.get(span.parent_id)
+
+
+def test_solve_wait_nests_under_the_solve_flush_on_both_paths():
+    tr = Tracer()
+    broker = _broker(backend="jax", tracer=tr)
+    profile = AppProfile.from_wcg_times(FIG2_TOPOLOGIES["linear"]())
+    broker.register("app", profile, ResponseTimeModel())
+    for i in range(3):
+        broker.submit("app", _env(0.5 + 2 * i))
+    broker.tick()
+    group = broker.register_batch("app", 4)
+    group.observe(
+        EnvArrays.from_envs([_env(0.3 + i) for i in range(4)]),
+        arrived=np.arange(4),
+    )
+    broker.tick()
+    spans = tr.spans()
+    waits = tr.spans("solve.wait")
+    assert len(waits) == 2  # one per flush: the request bucket, the group
+    for w in waits:
+        flush = _parent(spans, w)
+        assert flush.name == "stage.solve_flush"
+        assert flush.t0 <= w.t0 and w.t1 <= flush.t1
+    request_flush, group_flush = (_parent(spans, w) for w in waits)
+    assert _parent(spans, request_flush).name == "broker.tick"
+    assert _parent(spans, group_flush).name == "stage.batch_group"
+
+
+def test_server_spans_cover_waits_and_reads_outside_frames(tmp_path):
+    tr = Tracer(capacity=100_000)
+    broker = _broker(tracer=tr)
+    profile = _profile(8, 4)
+    broker.register("app", profile, ResponseTimeModel())
+    server, thread = _serve(tmp_path, broker, tracer=tr)
+    try:
+        _submit_and_tick(server, profile, [_env(0.5 + i) for i in range(3)])
+    finally:
+        server.stop()
+        thread.join(timeout=10)
+    spans = tr.spans()
+    names = {s.name for s in spans}
+    assert {"server.wait", "wire.read", "wire.frame", "broker.tick"} <= names
+    parents = {s.parent_id for s in spans}
+    for s in spans:
+        if s.name in ("server.wait", "wire.read"):
+            assert s.parent_id is None  # top-level, never around a frame
+            assert s.span_id not in parents  # holds no span at all
+    frames = tr.spans("wire.frame")
+    assert all(f.parent_id is None for f in frames)
+    # one request's spans share its id: the submit frame and the tick
+    submit_ids = [f.attrs["id"] for f in frames if f.attrs["type"] == "submit"]
+    (tick,) = tr.spans("broker.tick")
+    assert len(submit_ids) == 3
+    assert tick.attrs["request_ids"] == submit_ids

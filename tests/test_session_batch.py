@@ -23,12 +23,9 @@ from repro.core import (
     ResponseTimeModel,
     SessionBatch,
     WeightedModel,
-    device_price_summary,
-    face_recognition_graph,
     linear_graph,
     loop_graph,
     mesh_graph,
-    price_trace,
     tick_sessions,
     tree_graph,
 )
@@ -339,60 +336,6 @@ def test_broker_feeds_group_latency_into_adaptive_weights(monkeypatch):
     broker.tick()
     assert [name for name, _ in seen] == ["a", "b"]  # every group reported
     assert all(lat >= 0.0 for _, lat in seen)
-
-
-# ----------------------------------------------------------------------
-# Device-resident pricing telemetry
-# ----------------------------------------------------------------------
-
-
-def test_device_price_summary_matches_host_report_within_f32():
-    profile = AppProfile.from_wcg_times(
-        face_recognition_graph(speedup=1.0, bandwidth_mbps=1.0)
-    )
-    model = ResponseTimeModel()
-    rng = np.random.default_rng(4)
-    envs = [
-        Environment.symmetric(float(b), 3.0) for b in np.geomspace(0.3, 9.0, 10)
-    ]
-    masks = rng.random((10, profile.n)) < 0.5
-    masks[:, ~profile.offloadable] = True
-    active = np.ones(10, dtype=bool)
-    active[7:] = False
-
-    out = device_price_summary(profile, model, envs, masks, active=active)
-    host = price_trace(profile, model, list(zip(envs, masks)))
-    act = active
-    assert out["partial_mean"] == pytest.approx(
-        float(np.asarray(host.partial_cost)[act].mean()), rel=1e-5
-    )
-    assert out["gain_min"] == pytest.approx(
-        float(np.asarray(host.gain)[act].min()), rel=1e-5
-    )
-    assert out["partial_max"] == pytest.approx(
-        float(np.asarray(host.partial_cost)[act].max()), rel=1e-5
-    )
-    assert out["no_offload_mean"] == pytest.approx(
-        float(np.asarray(host.no_offload_cost)[act].mean()), rel=1e-5
-    )
-
-
-def test_batch_group_carries_device_summary_when_enabled():
-    profile = AppProfile.from_wcg_times(FIG2_TOPOLOGIES["linear"]())
-    broker = _broker(backend="jax")
-    broker.register("app", profile, ResponseTimeModel())
-    group = broker.register_batch("app", 6, device_telemetry=True)
-    group.observe(
-        EnvArrays.from_envs([Environment.symmetric(2.0, 3.0)] * 6),
-        arrived=np.arange(6),
-    )
-    broker.tick()
-    (rep,) = group.drain()
-    assert rep.device_summary is not None
-    assert set(rep.device_summary) >= {"partial_mean", "gain_mean"}
-    assert rep.device_summary["partial_mean"] == pytest.approx(
-        float(rep.partial_cost[rep.active].mean()), rel=1e-5
-    )
 
 
 # ----------------------------------------------------------------------
