@@ -29,11 +29,17 @@ tenant and keeps it warm across process restarts via
 document guarded by a schema version, the quantizer step, and a
 :func:`profile_fingerprint` of the application profile, so a stale or
 foreign snapshot degrades to a cold cache instead of serving wrong
-placements.
+placements.  The document (version 2) carries the entries as two packed
+arrays, base64 inside the JSON: the ``(count, 6)`` little-endian int64
+keys and the ``np.packbits`` of the ``(count, n)`` masks, both oldest
+entry first; a cache whose masks differ in length writes the version-1
+document of one ``{"key", "mask"}`` object per entry, which
+:meth:`PlacementCache.load` reads too.
 """
 
 from __future__ import annotations
 
+import base64
 import dataclasses
 import hashlib
 import json
@@ -57,7 +63,11 @@ __all__ = [
 ]
 
 # Bump when the snapshot schema changes; load() ignores unknown versions.
-SNAPSHOT_VERSION = 1
+# Version 1 (one {"key", "mask"} object per entry) is still read, and is
+# still written for a cache whose masks differ in length.
+SNAPSHOT_VERSION = 2
+_V1 = 1
+_KEY_WIDTH = 6  # EnvQuantizer.key: up, down, speedup, p_compute, p_idle, p_transfer
 
 
 def profile_fingerprint(obj) -> str:
@@ -364,6 +374,25 @@ class PlacementCache:
             m[3].set(0)
 
     # -- persistence -----------------------------------------------------
+    def _packed(self) -> tuple[np.ndarray, np.ndarray] | None:
+        """The entries as ``(count, 6)`` int64 keys and ``(count, n)`` bool
+        masks, oldest first; ``None`` where they do not stack: masks of
+        more than one shape, or keys that are not six int64 bins (which
+        only a direct caller of :meth:`store` can make)."""
+        count = len(self._entries)
+        if count == 0:
+            return np.zeros((0, _KEY_WIDTH), np.int64), np.zeros((0, 0), bool)
+        masks = list(self._entries.values())
+        if len({m.shape for m in masks}) != 1 or masks[0].ndim != 1:
+            return None
+        try:
+            keys = np.array(list(self._entries), dtype=np.int64)
+        except (TypeError, ValueError, OverflowError):
+            return None
+        if keys.shape != (count, _KEY_WIDTH):
+            return None
+        return keys, np.concatenate(masks).reshape(count, -1)
+
     def snapshot(
         self,
         *,
@@ -371,6 +400,14 @@ class PlacementCache:
         meta: dict | None = None,
     ) -> dict:
         """JSON-serializable snapshot of the entries (oldest → newest).
+
+        The version-2 document holds ``n`` (the mask length), ``count``,
+        ``keys`` (base64 of the ``(count, 6)`` little-endian int64 key
+        array) and ``masks`` (base64 of ``np.packbits`` of the
+        ``(count, n)`` mask array along its rows), both in LRU order,
+        oldest first.  A cache whose masks differ in length gets the
+        version-1 document instead: ``entries``, one ``{"key": [...],
+        "mask": [0/1, ...]}`` object per entry in the same order.
 
         ``fingerprint`` should be :func:`profile_fingerprint` of the
         profile the masks were computed for; :meth:`load` uses it to
@@ -386,11 +423,20 @@ class PlacementCache:
             "version": SNAPSHOT_VERSION,
             "fingerprint": fingerprint,
             "rel_step": self.quantizer.rel_step,
-            "entries": [
+        }
+        packed = self._packed()
+        if packed is None:
+            doc["version"] = _V1
+            doc["entries"] = [
                 {"key": [int(x) for x in k], "mask": [int(b) for b in v]}
                 for k, v in self._entries.items()
-            ],
-        }
+            ]
+        else:
+            keys, masks = packed
+            doc["n"] = masks.shape[1]
+            doc["count"] = len(keys)
+            doc["keys"] = _b64(keys.astype("<i8"))
+            doc["masks"] = _b64(np.packbits(masks, axis=1))
         if meta is not None:
             doc["meta"] = dict(meta)
         return doc
@@ -401,8 +447,9 @@ class PlacementCache:
         *,
         fingerprint: str | None = None,
         meta: dict | None = None,
-    ) -> None:
-        """Atomically write the snapshot to ``path``.
+    ) -> int:
+        """Atomically write the snapshot to ``path``; returns its size in
+        bytes.
 
         The document is serialized to a temporary file in the same
         directory and ``os.replace``d over the target, so a crash (or a
@@ -413,12 +460,12 @@ class PlacementCache:
         payload = (
             json.dumps(self.snapshot(fingerprint=fingerprint, meta=meta))
             + "\n"
-        )
+        ).encode()
         fd, tmp = tempfile.mkstemp(
             dir=path.parent or ".", prefix=f".{path.name}.", suffix=".tmp"
         )
         try:
-            with os.fdopen(fd, "w") as f:
+            with os.fdopen(fd, "wb") as f:
                 f.write(payload)
             os.replace(tmp, path)
         except BaseException:
@@ -427,6 +474,7 @@ class PlacementCache:
             except OSError:
                 pass
             raise
+        return len(payload)
 
     def load(
         self,
@@ -440,7 +488,8 @@ class PlacementCache:
         Forgiving by design — a serving restart must never crash on a
         stale artifact, it just cold-starts: a missing/corrupt file, an
         unknown schema version, a quantizer ``rel_step`` mismatch (bins
-        are not comparable) or a profile-fingerprint mismatch loads
+        are not comparable), a profile-fingerprint mismatch, or version-2
+        arrays whose decoded sizes disagree with ``count`` and ``n`` load
         nothing; individually malformed or wrong-length entries are
         skipped.  Entries land through :meth:`store`, so a snapshot
         larger than ``capacity`` is evicted down to capacity keeping the
@@ -472,7 +521,10 @@ class PlacementCache:
                 return 0, None
         else:
             doc = source
-        if not isinstance(doc, dict) or doc.get("version") != SNAPSHOT_VERSION:
+        if not isinstance(doc, dict):
+            return 0, None
+        version = doc.get("version")
+        if version not in (_V1, SNAPSHOT_VERSION):
             return 0, None
         if fingerprint is not None and doc.get("fingerprint") != fingerprint:
             return 0, None
@@ -482,16 +534,11 @@ class PlacementCache:
             return 0, None
         if not math.isclose(rel, self.quantizer.rel_step, rel_tol=1e-9):
             return 0, None
-        entries = doc.get("entries")
-        if not isinstance(entries, list):
+        rows = _v1_rows(doc) if version == _V1 else _v2_rows(doc)
+        if rows is None:
             return 0, None
         loaded = 0
-        for e in entries:
-            try:
-                key = tuple(int(x) for x in e["key"])
-                mask = np.asarray(e["mask"], dtype=bool)
-            except (TypeError, ValueError, KeyError):
-                continue
+        for key, mask in rows:
             if mask.ndim != 1 or mask.size == 0:
                 continue
             if expected_n is not None and mask.shape != (expected_n,):
@@ -514,3 +561,45 @@ class PlacementCache:
         cache = cls(quantizer, capacity=capacity)
         cache.load(source, fingerprint=fingerprint)
         return cache
+
+
+def _b64(a: np.ndarray) -> str:
+    return base64.b64encode(a.tobytes()).decode("ascii")
+
+
+def _v1_rows(doc: dict) -> list | None:
+    """(key, mask) pairs of a version-1 document, skipping malformed
+    entries; ``None`` where ``entries`` is not a list."""
+    entries = doc.get("entries")
+    if not isinstance(entries, list):
+        return None
+    rows = []
+    for e in entries:
+        try:
+            key = tuple(int(x) for x in e["key"])
+            mask = np.asarray(e["mask"], dtype=bool)
+        except (TypeError, ValueError, KeyError):
+            continue
+        rows.append((key, mask))
+    return rows
+
+
+def _v2_rows(doc: dict):
+    """(key, mask) pairs of a version-2 document in its order; ``None``
+    where a field is missing, the base64 is bad, or the decoded byte
+    counts disagree with ``count``, ``n`` and the key width."""
+    count, n = doc.get("count"), doc.get("n")
+    if not (isinstance(count, int) and isinstance(n, int)) or count < 0 or n < 0:
+        return None
+    try:
+        keys = base64.b64decode(doc["keys"], validate=True)
+        masks = base64.b64decode(doc["masks"], validate=True)
+    except (KeyError, TypeError, ValueError):  # binascii.Error is a ValueError
+        return None
+    row_bytes = (n + 7) // 8
+    if len(keys) != count * _KEY_WIDTH * 8 or len(masks) != count * row_bytes:
+        return None
+    keys = np.frombuffer(keys, dtype="<i8").reshape(count, _KEY_WIDTH)
+    bits = np.frombuffer(masks, dtype=np.uint8).reshape(count, row_bytes)
+    masks = np.unpackbits(bits, axis=1, count=n).astype(bool)
+    return zip(map(tuple, keys.tolist()), masks)

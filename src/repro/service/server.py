@@ -323,14 +323,19 @@ class SolverServer:
         seq = self.journal.seq
         meta = {"journal_seq": seq, "tick": self.broker._tick}
         for name, t in self.broker._tenants.items():
-            t.cache.save(
-                self._tenant_snapshot_path(name),
-                fingerprint=t.fingerprint,
-                meta=meta,
-            )
+            with self._span(
+                "snapshot.save", tenant=name, entries=len(t.cache)
+            ) as span:
+                nbytes = t.cache.save(
+                    self._tenant_snapshot_path(name),
+                    fingerprint=t.fingerprint,
+                    meta=meta,
+                )
+                span.set(bytes=nbytes)
         self._snapshot_seq = seq
         if self.compact_journal:
-            self.journal.compact(seq)
+            with self._span("snapshot.compact"):
+                self.journal.compact(seq)
         return seq
 
     def recover(self) -> dict:

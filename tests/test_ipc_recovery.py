@@ -9,6 +9,7 @@ subprocesses over real unix sockets; reads are timeout-bounded so a
 protocol hang is a failure, not a CI deadlock.
 """
 
+import json
 import os
 import pathlib
 import signal
@@ -22,6 +23,7 @@ import numpy as np
 import pytest
 
 from repro.core import AppProfile, Environment, ResponseTimeModel, random_wcg
+from repro.core.placement_cache import SNAPSHOT_VERSION
 from repro.service import (
     BrokerClient,
     BrokerSession,
@@ -148,6 +150,10 @@ def test_sigkill_warm_restart_replies_bit_identical(tmp_path):
         client.tick()
     proc.wait(timeout=TIMEOUT)
     assert proc.returncode == -signal.SIGKILL
+    # the restart warm-starts from a packed (version 2) cache snapshot
+    snap = json.loads((dir_b / "snaps" / "app.snapshot.json").read_text())
+    assert snap["version"] == SNAPSHOT_VERSION == 2
+    assert snap["count"] > 0 and snap["meta"]["tick"] == 14
 
     proc = _start_server(dir_b)  # warm restart against snapshot + journal
     try:

@@ -647,9 +647,10 @@ def test_tracer_opens_one_annotation_per_span_in_nesting_order():
     assert isinstance(profiler_annotation("wire.read"), jax.profiler.TraceAnnotation)
 
 
-def _serve(tmp_path, broker, tracer=None):
+def _serve(tmp_path, broker, tracer=None, **kwargs):
     server = SolverServer(
-        broker, address=unix_address(tmp_path / "srv.sock"), tracer=tracer
+        broker, address=unix_address(tmp_path / "srv.sock"), tracer=tracer,
+        **kwargs,
     )
     server.bind()
     thread = threading.Thread(
@@ -812,3 +813,34 @@ def test_server_spans_cover_waits_and_reads_outside_frames(tmp_path):
     (tick,) = tr.spans("broker.tick")
     assert len(submit_ids) == 3
     assert tick.attrs["request_ids"] == submit_ids
+
+
+def test_snapshot_pass_splits_into_one_save_per_tenant_then_compaction(tmp_path):
+    tr = Tracer(capacity=100_000)
+    broker = _broker(tracer=tr)
+    profile = _profile(8, 4)
+    broker.register("app", profile, ResponseTimeModel())
+    broker.register("other", _profile(6, 5), ResponseTimeModel())
+    server, thread = _serve(
+        tmp_path, broker, tracer=tr, journal_path=tmp_path / "journal.jsonl",
+        snapshot_dir=tmp_path / "snaps", snapshot_every_ticks=1,
+    )
+    try:
+        _submit_and_tick(server, profile, [_env(0.5 + i) for i in range(3)])
+    finally:
+        server.stop()
+        thread.join(timeout=10)
+    spans = tr.spans()
+    (snapshot,) = tr.spans("wire.snapshot")
+    children = [s for s in spans if s.parent_id == snapshot.span_id]
+    assert [s.name for s in children] == [
+        "snapshot.save", "snapshot.save", "snapshot.compact"
+    ]
+    for save in children[:2]:
+        tenant = save.attrs["tenant"]
+        path = tmp_path / "snaps" / f"{tenant}.snapshot.json"
+        assert save.attrs["entries"] == len(broker.tenant(tenant).cache)
+        assert save.attrs["bytes"] == path.stat().st_size
+    assert [s.attrs["tenant"] for s in children[:2]] == ["app", "other"]
+    assert children[0].attrs["entries"] > 0
+    assert all(snapshot.t0 <= s.t0 and s.t1 <= snapshot.t1 for s in children)

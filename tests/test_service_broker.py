@@ -395,24 +395,118 @@ def test_snapshot_guards_fall_back_to_cold_cache(tmp_path):
 
 
 def test_snapshot_load_skips_malformed_entries_and_evicts_to_capacity():
-    cache = PlacementCache()
-    for i, bw in enumerate((1.0, 2.0, 4.0, 8.0)):
-        cache.put(Environment.symmetric(bw, 3.0), np.array([True, i % 2 == 0]))
-    doc = cache.snapshot()
+    """The version-1 reader, on a hand-built document of one
+    ``{"key", "mask"}`` object per entry (what earlier releases wrote)."""
+    q = EnvQuantizer()
+    envs = [Environment.symmetric(bw, 3.0) for bw in (1.0, 2.0, 4.0, 8.0)]
+    doc = {
+        "version": 1,
+        "fingerprint": "fp",
+        "rel_step": q.rel_step,
+        "entries": [
+            {"key": list(q.key(env)), "mask": [1, int(i % 2 == 0)]}
+            for i, env in enumerate(envs)
+        ],
+        "meta": {"journal_seq": 7, "tick": 3},
+    }
     doc["entries"].insert(0, {"key": ["x"], "mask": [1]})      # bad key
     doc["entries"].insert(0, {"key": [1, 2], "mask": []})      # empty mask
     doc["entries"].insert(0, {"mask": [1]})                    # missing key
 
     small = PlacementCache(capacity=2)
-    assert small.load(doc) == 4          # good entries loaded (then evicted)
+    loaded, meta = small.load_with_meta(doc, fingerprint="fp")
+    assert loaded == 4                   # good entries loaded (then evicted)
+    assert meta == {"journal_seq": 7, "tick": 3}
     assert len(small) == 2               # evicted down to capacity...
     # ...keeping the newest entries (last written wins LRU)
-    assert small.get(Environment.symmetric(8.0, 3.0)) is not None
+    got = small.get(Environment.symmetric(8.0, 3.0), expected_n=2)
+    assert got is not None and got.tolist() == [True, False]
     assert small.get(Environment.symmetric(1.0, 3.0)) is None
+    assert small.stats.hits == 1 and small.stats.misses == 1
+    # the guards hold for version 1 as for version 2
+    assert PlacementCache().load(doc, fingerprint="other") == 0
 
     # wrong-length entries are skipped when the caller pins a profile size
     sized = PlacementCache()
     assert sized.load(doc, expected_n=3) == 0
+
+
+def _random_cache(count, n, *, seed=0):
+    rng = np.random.default_rng(seed)
+    cache = PlacementCache(capacity=count)
+    while len(cache) < count:
+        key = tuple(int(x) for x in rng.integers(-2**31, 2**31, 6))
+        cache.store(key, rng.random(n) < 0.5)
+    return cache
+
+
+def test_snapshot_v2_roundtrip_keeps_keys_masks_and_lru_order(tmp_path):
+    cache = _random_cache(4096, 90)
+    # touch a few old entries so the LRU order is not the insertion order
+    for key in list(cache._entries)[:5]:
+        assert cache.lookup(key) is not None
+    path = tmp_path / "cache.json"
+    nbytes = cache.save(path, fingerprint="fp", meta={"tick": 8})
+    assert nbytes == path.stat().st_size
+    doc = json.loads(path.read_text())
+    assert doc["version"] == SNAPSHOT_VERSION == 2
+    assert (doc["count"], doc["n"]) == (4096, 90)
+    assert "entries" not in doc
+
+    warm = PlacementCache(capacity=4096)
+    assert warm.load_with_meta(path, fingerprint="fp") == (4096, {"tick": 8})
+    assert list(warm._entries) == list(cache._entries)          # LRU order
+    for key, mask in cache._entries.items():
+        assert type(key[0]) is int
+        np.testing.assert_array_equal(warm._entries[key], mask)
+    # a smaller cache keeps the newest entries, as store() evicts
+    small = PlacementCache(capacity=10)
+    assert small.load(path) == 4096
+    assert list(small._entries) == list(cache._entries)[-10:]
+    # callers pinned to another graph size load nothing
+    assert PlacementCache().load(path, expected_n=9) == 0
+
+
+@pytest.mark.parametrize("field, value", [
+    ("keys", "not*base64!"),                       # bad base64
+    ("masks", "AAAA"),                             # masks too short
+    ("count", 3),                                  # count disagrees with keys
+    ("n", 17),                                     # n needs more mask bytes
+    ("keys", None),                                # not a string
+    ("count", "2"),                                # not an int
+])
+def test_snapshot_v2_with_bad_arrays_cold_starts(tmp_path, field, value):
+    cache = _random_cache(2, 9)
+    doc = cache.snapshot(fingerprint="fp", meta={"tick": 8})
+    bad = {**doc, field: value}
+    fresh = PlacementCache()
+    assert fresh.load_with_meta(bad, fingerprint="fp") == (0, None)
+    assert len(fresh) == 0
+    path = tmp_path / "bad.json"
+    path.write_text(json.dumps(bad))
+    assert PlacementCache().load(path) == 0
+    # the untouched document loads
+    assert PlacementCache().load(doc, fingerprint="fp") == 2
+
+
+def test_snapshot_with_mixed_mask_lengths_uses_the_v1_writer(tmp_path):
+    cache = PlacementCache()
+    masks = {(1, 2, 3, 4, 5, 6): np.array([True, False]),
+             (1, 2, 3, 4, 5, 7): np.array([False, True, True]),
+             (-(2**31), 0, 0, 0, 0, 0): np.array([True])}
+    for key, mask in masks.items():
+        cache.store(key, mask)
+    path = tmp_path / "mixed.json"
+    cache.save(path, fingerprint="fp")
+    doc = json.loads(path.read_text())
+    assert doc["version"] == 1 and len(doc["entries"]) == 3
+    warm = PlacementCache()
+    assert warm.load(path, fingerprint="fp") == 3
+    assert list(warm._entries) == list(masks)
+    for key, mask in masks.items():
+        np.testing.assert_array_equal(warm._entries[key], mask)
+    # a size-pinned caller takes only its own length
+    assert PlacementCache().load(path, expected_n=3) == 1
 
 
 def test_profile_fingerprint_distinguishes_profiles():
