@@ -594,9 +594,10 @@ def mcop_batch(
         single-device dispatch, a ``Mesh`` shards over exactly that
         fleet.  Results are bit-identical either way.
       tracer:   optional :class:`~repro.obs.trace.Tracer` — the wait for
-        each bucket's results is a ``solve.wait`` span, and on the sharded
-        path it holds one ``solve.shard`` span per device (shard index,
-        device count, row count).
+        each bucket's results is a ``solve.wait`` span; on the sharded
+        path a ``solve.shard_pack`` span covers the host's packing before
+        it, and the wait holds one ``solve.shard`` span per device (shard
+        index, device count, row count).
     Returns:
       ``list[MCOPResult]`` in input order; ``result[i].local_mask`` is
       ``(n_i,)`` bool over graph ``i``'s ORIGINAL vertices (padding
@@ -772,8 +773,9 @@ def solve_envs(
         single-device program, a ``Mesh`` shards over exactly that
         fleet.  Sharded results are bit-identical to unsharded.
       tracer:  optional :class:`~repro.obs.trace.Tracer` — the wait for
-        the results is a ``solve.wait`` span, holding one
-        ``solve_envs.shard`` span per device on the sharded path.
+        the results is a ``solve.wait`` span; on the sharded path a
+        ``solve.shard_pack`` span covers the host's packing before it,
+        and the wait holds one ``solve_envs.shard`` span per device.
     Returns:
       ``list[MCOPResult]``, one per environment in input order, masks
       ``(n,)`` bool over the profile's vertices.

@@ -25,6 +25,12 @@ Input buffers are donated to the compiled program (``donate_argnums`` on
 the batch pytree) except on the CPU backend, where XLA cannot reuse
 donated host buffers and would warn on every dispatch.
 
+The fleet's programs carry names of their own, so a device trace tells
+them from the single-device ones: ``jit__mcop_fleet_solve`` (the packed
+flush) and ``jit__mcop_fleet_fused`` (the fused build+solve).  With a
+tracer, a ``solve.shard_pack`` span covers each flush's padding,
+permutation and dispatch enqueue on the host.
+
 ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` simulates an
 N-device fleet on a CPU host — that is how the parity tests and
 ``benchmarks/shard.py`` exercise this module without a TPU pod.
@@ -42,6 +48,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.launch.mesh import make_solver_mesh
+from repro.obs.trace import NULL_SPAN
 from repro.runtime.sharding import solve_batch_spec, solver_axis, solver_shards
 
 __all__ = [
@@ -157,13 +164,13 @@ def _sharded_dispatch(mesh: Mesh, backend: str, interpret: bool | None):
 
         spec = solve_batch_spec(mesh)
 
-        def solve(adj, wl, wc, pin):
+        def _mcop_fleet_solve(adj, wl, wc, pin):
             return _dispatch_arrays(adj, wl, wc, pin, backend, interpret)
 
         # check_vma=False: the bodies contain while_loop / pallas_call,
         # which shard_map's varying-axes checker cannot see through.
         sharded = jax.shard_map(
-            solve,
+            _mcop_fleet_solve,
             mesh=mesh,
             in_specs=(spec, spec, spec, spec),
             out_specs=(spec, spec),
@@ -176,11 +183,27 @@ def _sharded_dispatch(mesh: Mesh, backend: str, interpret: bool | None):
     return fn
 
 
+def _pack_span(tracer, plan: ShardPlan, nbytes: int):
+    """``solve.shard_pack`` over a flush's host packing: the inert
+    padding, the round-robin permutation and the dispatch enqueue.
+    ``k`` true rows, ``pad`` inert rows, ``devices``, and ``bytes`` the
+    host bytes packed (the padded, permuted inputs)."""
+    if tracer is None:
+        return NULL_SPAN
+    return tracer.span(
+        "solve.shard_pack", k=plan.k, pad=plan.pad, devices=plan.shards,
+        bytes=nbytes,
+    )
+
+
 def _emit_shard_spans(tracer, plan: ShardPlan, outputs, *, stage: str):
-    """Per-shard completion spans: ``<stage>.shard`` with the device's
-    row count; duration is the host-observed wait for that device's
-    output buffer (a real measurement — on a fleet the earliest shards
-    return while later ones still solve)."""
+    """Per-shard spans ``<stage>.shard`` with the device's row count.
+
+    The host blocks on the devices' output buffers one after another, in
+    shard order, so each span ends at the host-observed completion of its
+    shard and its duration is incremental: only the part of the wait left
+    once the shard before it was ready (the first starts as the wait
+    does).  A shard that finished before its predecessor reads ~0."""
     if tracer is None:
         return
     cuts = outputs[0]
@@ -220,17 +243,19 @@ def sharded_dispatch_arrays(
     pin = np.asarray(pin)
     k, m = wl.shape
     plan = shard_plan(k, solver_shards(mesh))
-    if plan.pad:
-        # inert rows: all-pinned, zero weights/edges — the anchor fold
-        # collapses them before any phase runs; cropped after the gather
-        adj = np.concatenate([adj, np.zeros((plan.pad, m, m), adj.dtype)])
-        wl = np.concatenate([wl, np.zeros((plan.pad, m), wl.dtype)])
-        wc = np.concatenate([wc, np.zeros((plan.pad, m), wc.dtype)])
-        pin = np.concatenate([pin, np.ones((plan.pad, m), pin.dtype)])
-    fn = _sharded_dispatch(mesh, backend, interpret)
-    cuts_sh, masks_sh = fn(
-        adj[plan.perm], wl[plan.perm], wc[plan.perm], pin[plan.perm]
-    )
+    row_bytes = sum(a.nbytes for a in (adj, wl, wc, pin)) // k
+    with _pack_span(tracer, plan, row_bytes * (k + plan.pad)):
+        if plan.pad:
+            # inert rows: all-pinned, zero weights/edges — the anchor fold
+            # collapses them before any phase runs; cropped after the gather
+            adj = np.concatenate([adj, np.zeros((plan.pad, m, m), adj.dtype)])
+            wl = np.concatenate([wl, np.zeros((plan.pad, m), wl.dtype)])
+            wc = np.concatenate([wc, np.zeros((plan.pad, m), wc.dtype)])
+            pin = np.concatenate([pin, np.ones((plan.pad, m), pin.dtype)])
+        fn = _sharded_dispatch(mesh, backend, interpret)
+        cuts_sh, masks_sh = fn(
+            adj[plan.perm], wl[plan.perm], wc[plan.perm], pin[plan.perm]
+        )
     from repro.core.mcop import _solve_wait  # deferred: cycle
 
     with _solve_wait(tracer):
@@ -267,10 +292,14 @@ def sharded_fused_solver(build_solve, mesh: Mesh, env_struct):
         out_specs=(spec, spec),
         check_vma=False,
     )
+
+    def _mcop_fleet_fused(t_local, data_in, data_out, pinned, env):
+        return sharded(t_local, data_in, data_out, pinned, env)
+
     # donate the env columns (the per-tick varying buffers); the profile
     # tensors are replicated constants the caller reuses across ticks
     donate = (4,) if _donate(mesh) else ()
-    return jax.jit(sharded, donate_argnums=donate)
+    return jax.jit(_mcop_fleet_fused, donate_argnums=donate)
 
 
 def sharded_solve_envs_call(
@@ -295,13 +324,15 @@ def sharded_solve_envs_call(
     cols = [np.asarray(c) for c in env_arrays]
     k = cols[0].shape[0]
     plan = shard_plan(k, solver_shards(mesh))
-    if plan.pad:
-        cols = [
-            np.concatenate([c, np.ones(plan.pad, c.dtype)]) for c in cols
-        ]
-    cols = [c[plan.perm] for c in cols]
-    env_sh = type(env_arrays)(*cols)
-    cuts_sh, masks_sh = fn(t_local, data_in, data_out, pinned, env_sh)
+    row_bytes = sum(c.itemsize for c in cols)
+    with _pack_span(tracer, plan, row_bytes * (k + plan.pad)):
+        if plan.pad:
+            cols = [
+                np.concatenate([c, np.ones(plan.pad, c.dtype)]) for c in cols
+            ]
+        cols = [c[plan.perm] for c in cols]
+        env_sh = type(env_arrays)(*cols)
+        cuts_sh, masks_sh = fn(t_local, data_in, data_out, pinned, env_sh)
     from repro.core.mcop import _solve_wait  # deferred: cycle
 
     with _solve_wait(tracer):

@@ -240,3 +240,49 @@ def test_sharded_tick_sessions_bit_identical_on_8_devices():
         print('OK')
         """
     )
+
+
+def test_fleet_flush_packs_in_a_span_and_names_its_programs():
+    run_sub(
+        """
+        import re
+        import numpy as np, jax
+        from repro.core import AppProfile, ResponseTimeModel, linear_graph
+        from repro.core.cost_models import EnvArrays
+        from repro.core.mcop import WCGBatch, _fused_solver, mcop_batch, solve_envs
+        from repro.core.mcop_shard import _sharded_dispatch, default_solver_mesh
+        from repro.obs import Tracer
+
+        mesh = default_solver_mesh()
+        graphs = [linear_graph(6, rng=np.random.default_rng(i)) for i in range(5)]
+        batch = WCGBatch.from_wcgs(graphs, m=16)
+        tr = Tracer()
+        mcop_batch(batch, backend='jax', mesh=mesh, tracer=tr)
+        (pack,) = tr.spans('solve.shard_pack')
+        # 5 rows + 3 inert on 4 devices; adj, two weight rows and the pins
+        assert pack.attrs == {'k': 5, 'pad': 3, 'devices': 4,
+                              'bytes': 8 * (16 * 16 * 4 + 2 * 16 * 4 + 16)}
+        (wait,) = tr.spans('solve.wait')
+        assert pack.t1 <= wait.t0
+
+        profile = AppProfile.from_wcg_times(graphs[0])
+        envs = EnvArrays(*(np.full(6, 2.0) for _ in range(6)))
+        tr = Tracer()
+        solve_envs(profile, ResponseTimeModel(), envs, backend='jax', mesh=mesh, tracer=tr)
+        (pack,) = tr.spans('solve.shard_pack')
+        assert (pack.attrs['k'], pack.attrs['pad'], pack.attrs['bytes']) == (6, 2, 8 * 6 * 4)
+
+        def module(lowered):
+            return re.search(r'module @(\\S+)', lowered.as_text()).group(1)
+
+        args = batch.adj[:4].astype(np.float32), *(np.zeros((4, 16), np.float32),) * 2
+        fleet = _sharded_dispatch(mesh, 'jax', None).lower(*args, np.ones((4, 16), bool))
+        assert module(fleet) == 'jit__mcop_fleet_solve'
+        fused = _fused_solver(ResponseTimeModel(), 'jax', None, mesh)
+        env4 = EnvArrays(*(np.ones(4, np.float32) for _ in range(6)))
+        m16 = np.zeros(16, np.float32), np.zeros((16, 16), np.float32), np.zeros((16, 16), np.float32)
+        assert module(fused.lower(*m16, np.ones(16, bool), env4)) == 'jit__mcop_fleet_fused'
+        print('OK')
+        """,
+        devices=4,
+    )
