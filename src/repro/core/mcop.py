@@ -27,7 +27,7 @@ Backend-selection story — when each wins:
   round-trips.  Use it for one graph per call on-device.
 
 * :func:`mcop_batch` — the throughput path.  Pads a heterogeneous list of
-  graphs into static shape *buckets* (default 16/64/256 vertices) and
+  graphs into static shape *buckets* (default 16/64/128/256 vertices) and
   ``vmap``s the jitted solver per bucket, so N environment points or N
   concurrent requests compile to ONE XLA program per bucket rather than N
   traces, and execute as one dispatch.  Amortizes dispatch overhead and
@@ -368,7 +368,7 @@ def mcop_jax(g: WCG) -> MCOPResult:
 # Batched solver — static shape buckets, one XLA program per bucket.
 # ======================================================================
 
-DEFAULT_BUCKETS = (16, 64, 256)
+DEFAULT_BUCKETS = (16, 64, 128, 256)
 
 
 @jax.jit

@@ -59,11 +59,49 @@ def _assert_matches_reference(graphs, results):
 # ----------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("bucket", [16, 64])
+@pytest.mark.parametrize("bucket", [16, 64, 128])
 def test_mcop_batch_matches_reference_per_bucket(bucket):
     """≥20 random graphs per bucket, mixed sizes and pinned-vertex sets."""
     graphs = _mixed_batch(bucket, count=22, seed0=1000 * bucket)
     _assert_matches_reference(graphs, mcop_batch(graphs))
+
+
+def _environments(k: int) -> list[Environment]:
+    """k environments whose bandwidths span three decades."""
+    rng = np.random.default_rng(k)
+    return [
+        Environment(
+            bandwidth_up=float(10 ** rng.uniform(0.5, 3.5)),
+            bandwidth_down=float(10 ** rng.uniform(0.5, 3.5)),
+            speedup=float(rng.uniform(1.5, 12.0)),
+        )
+        for _ in range(k)
+    ]
+
+
+def _assert_bit_identical(results, expected):
+    for a, b in zip(results, expected, strict=True):
+        assert a.min_cut == b.min_cut
+        assert np.array_equal(a.local_mask, b.local_mask)
+
+
+@pytest.mark.parametrize("k", [1, 7, 33])
+def test_layer_split_graphs_solve_at_bucket_128_as_at_256(granite_layer_split, k):
+    """A 90-vertex graph lands in the 128 bucket, and its cuts and masks
+    are bit-identical to the same batch padded to 256, packed per graph
+    and built inside the fused program alike."""
+    from repro.core.mcop import DEFAULT_BUCKETS, _bucket_size, solve_envs
+
+    profile, envs, model = granite_layer_split, _environments(k), ResponseTimeModel()
+    graphs = [model.build(profile, e) for e in envs]
+    assert profile.n == 90 and _bucket_size(profile.n, DEFAULT_BUCKETS) == 128
+    at_128 = mcop_batch(graphs)
+    _assert_bit_identical(at_128, mcop_batch(graphs, buckets=(256,)))
+    _assert_bit_identical(
+        solve_envs(profile, model, envs),
+        solve_envs(profile, model, envs, buckets=(256,)),
+    )
+    _assert_matches_reference(graphs[:1], at_128[:1])
 
 
 def test_mcop_batch_mixed_buckets_preserves_order():

@@ -14,6 +14,8 @@ against the forced single-device path with ``==`` — no tolerances:
 * a full ``tick_sessions`` tick — every event column, prices, cache
   counters — plus the empty-miss-set second tick (no solve dispatched;
   the sharded plane must stay out of the way entirely).
+* Granite-34B-Code's 90-vertex layer split at bucket 128, against the
+  single-device flush at 128 and at 256; its pack moves 128² rows.
 """
 
 import os
@@ -237,6 +239,54 @@ def test_sharded_tick_sessions_bit_identical_on_8_devices():
             assert np.array_equal(rs.full_offload_cost, r1.full_offload_cost), t
         assert sharded[0].solved > 0      # tick 0 really flushed
         assert sharded[1].solved == 0     # tick 1 really was empty
+        print('OK')
+        """
+    )
+
+
+def test_fleet_flush_at_bucket_128_bit_identical_on_8_devices():
+    """Granite's 90-vertex layer split flushes at bucket 128 on the fleet:
+    bit-identical to the single-device flush at 128 and at 256, and the
+    pack moves rows of 128² adjacency, not 256²."""
+    run_sub(
+        """
+        import numpy as np, jax
+        from repro.configs import get_config
+        from repro.configs.base import ShapeConfig
+        from repro.core import Environment, ResponseTimeModel
+        from repro.core.graph import WCGBatch
+        from repro.core.mcop import DEFAULT_BUCKETS, _bucket_size, mcop_batch
+        from repro.core.mcop_shard import default_solver_mesh
+        from repro.obs import Tracer
+        from repro.profilers.program import app_profile_from_config
+
+        assert jax.device_count() == 8
+        mesh = default_solver_mesh()
+        profile = app_profile_from_config(
+            get_config('granite-34b'), ShapeConfig('decode_8k', 'decode', 8192, 128),
+            local_flops_per_s=1e13)
+        m = _bucket_size(profile.n, DEFAULT_BUCKETS)
+        assert (profile.n, m) == (90, 128)
+        rng = np.random.default_rng(11)
+        k = 13  # uneven on 8 shards: pad=3 + round-robin both engaged
+        graphs = [ResponseTimeModel().build(profile, Environment(
+                      bandwidth_up=float(10 ** rng.uniform(0.5, 3.5)),
+                      bandwidth_down=float(10 ** rng.uniform(0.5, 3.5)),
+                      speedup=float(rng.uniform(1.5, 12.0))))
+                  for _ in range(k)]
+
+        tr = Tracer()
+        sharded = mcop_batch(WCGBatch.from_wcgs(graphs, m=m), mesh=mesh, tracer=tr)
+        single = mcop_batch(WCGBatch.from_wcgs(graphs, m=m), mesh=False)
+        at_256 = mcop_batch(WCGBatch.from_wcgs(graphs, m=256), mesh=False)
+        for rs, r1, r2 in zip(sharded, single, at_256, strict=True):
+            assert rs.min_cut == r1.min_cut == r2.min_cut
+            assert np.array_equal(rs.local_mask, r1.local_mask)
+            assert np.array_equal(rs.local_mask, r2.local_mask)
+        (pack,) = tr.spans('solve.shard_pack')
+        # float32 adjacency, two weight rows and the pins, for 13 + 3 rows
+        assert (pack.attrs['k'], pack.attrs['pad']) == (13, 3)
+        assert pack.attrs['bytes'] == 16 * (128 * 128 * 4 + 2 * 128 * 4 + 128)
         print('OK')
         """
     )

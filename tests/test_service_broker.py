@@ -102,6 +102,34 @@ def test_tick_issues_at_most_one_mcop_batch_call_per_bucket(monkeypatch):
         )
 
 
+def test_layer_split_tenant_flushes_at_bucket_128(granite_layer_split):
+    """A 90-vertex tenant (Granite-34B-Code split per layer) flushes in
+    the 128 bucket: the tick report, the flush span and the dispatch
+    timer's label all say so.  An 8-vertex tenant still flushes at 16."""
+    from repro.obs import MetricsRegistry, Tracer
+
+    assert granite_layer_split.n == 90
+    tracer, metrics = Tracer(), MetricsRegistry()
+    broker = _broker(backend="jax", tracer=tracer, metrics=metrics)
+    broker.register("granite", granite_layer_split, ResponseTimeModel())
+    broker.register("small", _profile(8, seed=0), ResponseTimeModel())
+
+    buckets = []
+    for name in ("granite", "small"):
+        fut = broker.submit(name, Environment.symmetric(30.0, 4.0))
+        report = broker.tick()
+        assert fut.done and report.dispatches == 1
+        buckets.append(report.buckets)
+    assert buckets == [(128,), (16,)]
+    flushes = tracer.spans("stage.solve_flush")
+    assert [s.attrs["bucket"] for s in flushes] == [128, 16]
+    for m in (128, 16):
+        timer = metrics.get_histogram(
+            "mcop_dispatch_duration_s", backend="jax", bucket=m, devices=1
+        )
+        assert timer is not None and timer.count == 1
+
+
 def test_second_tick_serves_same_bins_from_cache(monkeypatch):
     calls = []
     real = broker_mod.mcop_batch

@@ -67,7 +67,7 @@ def _has_kernel(compiled) -> bool:
     return "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("n", [16, 64, 256])
+@pytest.mark.parametrize("n", [16, 64, 128, 256])
 def test_stoer_wagner_kernel_compiles(one_chip, n):
     adj, wl, wc, pin = _shapes(one_chip, (BATCH, n, n), (BATCH, n), (BATCH, n), (BATCH, n))
     solve = jax.jit(
@@ -78,7 +78,7 @@ def test_stoer_wagner_kernel_compiles(one_chip, n):
     assert default_block_graphs(n, False) == 8
 
 
-@pytest.mark.parametrize("n", [16, 64, 256])
+@pytest.mark.parametrize("n", [16, 64, 128, 256])
 def test_jax_batch_solver_compiles(one_chip, n):
     adj, wl, wc = _shapes(one_chip, (BATCH, n, n), (BATCH, n), (BATCH, n))
     (pin,) = _shapes(one_chip, (BATCH, n), dtype=jnp.bool_)
