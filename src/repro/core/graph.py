@@ -9,7 +9,7 @@ The canonical representation here is dense: a symmetric ``(n, n)`` adjacency
 matrix of edge weights (0 == no edge) plus per-vertex cost vectors.  Dense
 is the right layout for this framework because (i) the paper's graphs are
 small-to-medium task graphs (|V| in the tens-to-thousands), (ii) the JAX
-implementation of MCOP (``mcop.mcop_jax``) wants MXU/VPU-friendly matrix
+implementation of MCOP (``mcop.mcop_batch``) wants MXU/VPU-friendly matrix
 ops, and (iii) merging vertices is a row/column add — O(n) — instead of
 pointer surgery.
 

@@ -17,21 +17,13 @@ Backend-selection story — when each wins:
   want the trace, f64 arithmetic, or are debugging; it is the semantic
   oracle everything else is tested against.
 
-* :func:`mcop_jax` (``backend="jax"``) — a dense, fully jittable JAX
-  implementation built on ``lax.fori_loop``.  Vertices are never
-  physically removed; merging is a masked row/column fold, membership is a
-  boolean matrix, and the inner most-tightly-connected-vertex scan is a
-  masked argmax.  O(|V|³) dense work is VPU/MXU-friendly and lets the
-  partitioner run *inside* a jitted training/serving loop — the paper's
-  "real-time online algorithm" requirement (§3.1) without host
-  round-trips.  Use it for one graph per call on-device.
-
-* :func:`mcop_batch` — the throughput path.  Pads a heterogeneous list of
-  graphs into static shape *buckets* (default 16/64/128/256 vertices) and
-  ``vmap``s the jitted solver per bucket, so N environment points or N
-  concurrent requests compile to ONE XLA program per bucket rather than N
-  traces, and execute as one dispatch.  Amortizes dispatch overhead and
-  keeps the batch resident on-device; this is what
+* :func:`mcop_batch` (``backend="jax"``) — the throughput path, and the
+  one every served flush takes.  Pads a heterogeneous list of graphs
+  into static shape *buckets* (default 16/64/128/256 vertices) and
+  ``vmap``s a jitted dense masked Stoer–Wagner per bucket, so N
+  environment points or N concurrent requests compile to ONE XLA program
+  per bucket rather than N traces, and execute as one dispatch.  Amortizes
+  dispatch overhead and keeps the batch resident on-device; this is what
   ``AdaptiveController.sweep`` and the placement tier sweep call.
 
 * ``mcop_batch(..., backend="pallas")`` — same bucketing, but each bucket
@@ -63,7 +55,6 @@ any phase cut; graphs with no unoffloadable vertex are anchored at vertex
 from __future__ import annotations
 
 import dataclasses
-import functools
 from collections import OrderedDict
 from typing import Sequence
 
@@ -78,7 +69,6 @@ __all__ = [
     "PhaseRecord",
     "MCOPResult",
     "mcop_reference",
-    "mcop_jax",
     "mcop_batch",
     "solve_envs",
     "mcop",
@@ -248,8 +238,11 @@ def mcop_reference(g: WCG, *, start: int | None = None) -> MCOPResult:
 
 
 # ======================================================================
-# JAX implementation — dense masked Stoer–Wagner with node-cost tuples.
+# Batched solver — dense masked Stoer–Wagner, static shape buckets, one
+# XLA program per bucket.
 # ======================================================================
+
+DEFAULT_BUCKETS = (16, 64, 128, 256)
 
 
 def _fold_pinned(adj, w_local, w_cloud, pinned):
@@ -272,124 +265,29 @@ def _fold_pinned(adj, w_local, w_cloud, pinned):
     wc = jnp.where(others, 0.0, w_cloud).at[src].set((w_cloud * pinned).sum()
                                                      + w_cloud[src] * (~pinned[src]))
 
-    alive = ~others
-    members = jnp.eye(n, dtype=bool)
-    members = members.at[src, :].set(members[src] | pinned)
-    return adj2, wl, wc, alive, members, src
-
-
-@functools.partial(jax.jit, static_argnames=())
-def _mcop_jax_impl(adj, w_local, w_cloud, pinned):
-    n = adj.shape[0]
-    c_local_total = w_local.sum()
-    adj, w_local, w_cloud, alive, members, src = _fold_pinned(
-        adj, w_local, w_cloud, pinned
-    )
-
-    def phase_body(_, carry):
-        adj, wl, wc, alive, members, src, best_cut, best_cloud = carry
-        n_alive = alive.sum()
-        valid_phase = n_alive >= 2
-        gains = wl - wc
-
-        # ---- inner MTCV scan (Algorithm 3) ---------------------------
-        def add_body(_, inner):
-            in_a, conn, s_reg, t_reg = inner
-            cand = alive & ~in_a
-            scores = jnp.where(cand, conn - gains, _NEG_INF)
-            v = jnp.argmax(scores)
-            do = cand.any()
-            in_a = jnp.where(do, in_a | (jnp.arange(n) == v), in_a)
-            conn = jnp.where(do, conn + adj[v], conn)
-            s_reg = jnp.where(do, t_reg, s_reg)
-            t_reg = jnp.where(do, v, t_reg)
-            return in_a, conn, s_reg, t_reg
-
-        in_a0 = alive & (jnp.arange(n) == src)
-        inner0 = (in_a0, adj[src], src, src)
-        _, _, s_reg, t_reg = jax.lax.fori_loop(0, n - 1, add_body, inner0)
-
-        # ---- Eq. 10 cut-of-the-phase ---------------------------------
-        comm = (adj[t_reg] * alive).sum()
-        cut = c_local_total - gains[t_reg] + comm
-        cut = jnp.where(valid_phase, cut, _POS_INF)
-
-        improved = cut < best_cut
-        best_cut = jnp.where(improved, cut, best_cut)
-        best_cloud = jnp.where(improved, members[t_reg], best_cloud)
-
-        # ---- Algorithm 1 merge of (s, t), masked ---------------------
-        do_merge = valid_phase & (s_reg != t_reg)
-
-        def merged(args):
-            adj, wl, wc, alive, members = args
-            t_row = adj[t_reg]
-            adj2 = adj.at[s_reg, :].add(t_row)
-            adj2 = adj2.at[:, s_reg].add(t_row)
-            adj2 = adj2.at[s_reg, s_reg].set(0.0)
-            tmask = jnp.arange(n) == t_reg
-            adj2 = adj2 * (~tmask[:, None]) * (~tmask[None, :])
-            wl2 = wl.at[s_reg].add(wl[t_reg]).at[t_reg].set(0.0)
-            wc2 = wc.at[s_reg].add(wc[t_reg]).at[t_reg].set(0.0)
-            alive2 = alive & ~tmask
-            members2 = members.at[s_reg, :].set(members[s_reg] | members[t_reg])
-            members2 = members2.at[t_reg, :].set(False)
-            return adj2, wl2, wc2, alive2, members2
-
-        adj, wl, wc, alive, members = jax.lax.cond(
-            do_merge, merged, lambda a: a, (adj, wl, wc, alive, members)
-        )
-        # anchor survives: if t was the source, s is the survivor
-        src = jnp.where(do_merge & (t_reg == src), s_reg, src)
-        return adj, wl, wc, alive, members, src, best_cut, best_cloud
-
-    best0 = jnp.asarray(_POS_INF, adj.dtype)
-    cloud0 = jnp.zeros(n, dtype=bool)
-    carry0 = (adj, w_local, w_cloud, alive, members, src, best0, cloud0)
-    out = jax.lax.fori_loop(0, n - 1, phase_body, carry0)
-    best_cut, best_cloud = out[6], out[7]
-    return best_cut, ~best_cloud  # local mask
-
-
-def mcop_jax(g: WCG) -> MCOPResult:
-    """Jittable MCOP.  Semantics match :func:`mcop_reference`."""
-    cut, local = _mcop_jax_impl(
-        jnp.asarray(g.adj, jnp.float64 if jax.config.jax_enable_x64 else jnp.float32),
-        jnp.asarray(g.w_local),
-        jnp.asarray(g.w_cloud),
-        jnp.asarray(~g.offloadable),
-    )
-    return MCOPResult(
-        min_cut=float(cut), local_mask=np.asarray(local), phases=[]
-    )
-
-
-# ======================================================================
-# Batched solver — static shape buckets, one XLA program per bucket.
-# ======================================================================
-
-DEFAULT_BUCKETS = (16, 64, 128, 256)
+    return adj2, wl, wc, ~others, src
 
 
 @jax.jit
 def _mcop_batch_impl(adj, w_local, w_cloud, pinned):
-    """Batch-optimized single-graph solver (vmapped below).
+    """Single-graph solver, vmapped below.
 
-    Same algorithm as :func:`_mcop_jax_impl`, restructured for throughput:
+    Vertices are never physically removed: a merge is a masked row/column
+    fold and the inner most-tightly-connected-vertex scan a masked argmax.
 
-    * ``lax.while_loop`` instead of fixed-bound ``fori_loop`` for both the
-      phase loop and the inner MTCV scan — JAX's while batching rule masks
-      finished lanes automatically, so each graph does exactly
-      Σ(n_alive−1) absorptions instead of (n−1)² and padded vertices cost
-      nothing (they are folded into the anchor before the first phase).
+    * ``lax.while_loop`` for both the phase loop and the inner MTCV scan —
+      JAX's while batching rule masks finished lanes automatically, so
+      each graph does exactly Σ(n_alive−1) absorptions instead of (n−1)²
+      and padded vertices cost nothing (they are folded into the anchor
+      before the first phase).
     * merged-group membership is a per-vertex representative *label*
       (union-find with full path compression: every merge relabels in
-      O(n)) instead of the O(n²) boolean membership matrix, which would
+      O(n)) instead of an O(n²) boolean membership matrix, which would
       otherwise dominate the while-loop carry at n ≳ 128.
     """
     n = adj.shape[0]
     c_local_total = w_local.sum()
-    adj, w_local, w_cloud, alive, _, src = _fold_pinned(
+    adj, w_local, w_cloud, alive, src = _fold_pinned(
         adj, w_local, w_cloud, pinned
     )
     idx = jnp.arange(n)
@@ -452,7 +350,7 @@ def _mcop_batch_impl(adj, w_local, w_cloud, pinned):
 
 # vmap over the batch-optimized solver; jit caches one executable per
 # (bucket_n, batch) shape pair.
-_mcop_jax_batch = jax.jit(jax.vmap(_mcop_batch_impl))
+_mcop_batch_jit = jax.jit(jax.vmap(_mcop_batch_impl))
 
 
 def _bucket_size(n: int, buckets: Sequence[int]) -> int:
@@ -496,7 +394,7 @@ def _solver_dtype(backend: str):
 def _dispatch_arrays(adj, wl, wc, pin, backend: str, interpret: bool | None):
     """One device dispatch over pre-packed (b, m[, m]) tensors."""
     if backend == "jax":
-        return _mcop_jax_batch(adj, wl, wc, pin)
+        return _mcop_batch_jit(adj, wl, wc, pin)
     # deferred: keep core importable without pulling kernel deps
     from repro.kernels.mcop_phase import mcop_stoer_wagner_kernel
 
@@ -884,15 +782,12 @@ def solve_envs(
 def mcop(g: WCG, *, backend: str = "reference") -> MCOPResult:
     """Front door used by the rest of the framework.
 
-    Backends: ``"reference"`` (numpy oracle with per-phase trace),
-    ``"jax"`` (jitted dense solver), ``"pallas"`` (single-graph batch
-    through the full Stoer–Wagner kernel).  For many graphs per call use
-    :func:`mcop_batch`.
+    Backends: ``"reference"`` (numpy oracle with per-phase trace), and
+    ``"jax"`` / ``"pallas"`` (a batch of one through :func:`mcop_batch`).
+    For many graphs per call use :func:`mcop_batch`.
     """
     if backend == "reference":
         return mcop_reference(g)
-    if backend == "jax":
-        return mcop_jax(g)
-    if backend == "pallas":
-        return mcop_batch([g], backend="pallas")[0]
+    if backend in ("jax", "pallas"):
+        return mcop_batch([g], backend=backend)[0]
     raise ValueError(f"unknown MCOP backend: {backend!r}")

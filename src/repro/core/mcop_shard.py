@@ -4,7 +4,7 @@ One broker flush produces a bucket's worth of WCG instances; this module
 splits that batch across every device of a 1-D ``("solve",)`` mesh (see
 ``repro.launch.mesh.make_solver_mesh``) with ``shard_map`` and gathers
 the cuts/masks back **bit-identically** to the single-device path.  The
-parity argument: the batched solvers (``_mcop_jax_batch``'s vmapped
+parity argument: the batched solvers (``_mcop_batch_jit``'s vmapped
 while_loop and the Pallas grid kernel) do strictly per-graph arithmetic —
 lane masking in a vmapped while_loop changes which lanes *update*, never
 the update math — so regrouping rows across devices cannot perturb a
@@ -32,8 +32,8 @@ tracer, a ``solve.shard_pack`` span covers each flush's padding,
 permutation and dispatch enqueue on the host.
 
 ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` simulates an
-N-device fleet on a CPU host — that is how the parity tests and
-``benchmarks/shard.py`` exercise this module without a TPU pod.
+N-device fleet on a CPU host — that is how the parity tests
+(``tests/test_mcop_shard.py``) exercise this module without a TPU pod.
 """
 
 from __future__ import annotations

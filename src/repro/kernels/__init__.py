@@ -5,7 +5,6 @@ in ``ops.py``:
 
 * ``flash_attention`` — online-softmax attention (causal/full/window, GQA)
 * ``mamba_chunk_scan`` — Mamba2 SSD chunked selective scan
-* ``mcop_phase``       — the paper's MinCutPhase inner loop (host phase loop)
 * ``mcop_stoer_wagner_kernel`` — full batched MCOP: all phases + merges in
   one kernel invocation, grid over graphs (see ``core.mcop.mcop_batch``)
 
@@ -17,7 +16,6 @@ from repro.kernels.ops import (
     default_interpret,
     flash_attention,
     mamba_chunk_scan,
-    mcop_min_cut,
     on_tpu,
 )
 from repro.kernels.mcop_phase import mcop_stoer_wagner_kernel
@@ -26,7 +24,6 @@ from repro.kernels import ref
 __all__ = [
     "flash_attention",
     "mamba_chunk_scan",
-    "mcop_min_cut",
     "mcop_stoer_wagner_kernel",
     "default_interpret",
     "on_tpu",

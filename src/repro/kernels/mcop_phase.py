@@ -1,11 +1,6 @@
-"""Pallas TPU kernels for MCOP (paper Algorithms 1–3) — phase and full solver.
+"""Pallas TPU kernels for MCOP (paper Algorithms 1–3) — full and fused solvers.
 
 Two kernels, one memory story:
-
-* :func:`mcop_phase_kernel` — ONE MinCutPhase (Algorithm 3) per invocation.
-  The host keeps the Algorithm-2 loop and the Algorithm-1 merges in numpy
-  (see ``repro.kernels.ops.mcop_min_cut``), so the adjacency crosses
-  HBM→VMEM once *per phase*: |V|−1 transfers per solve.
 
 * :func:`mcop_stoer_wagner_kernel` — the FULL modified Stoer–Wagner in a
   single kernel invocation, batched over graphs.  All |V|−1 phases, the
@@ -16,6 +11,10 @@ Two kernels, one memory story:
   throughput shape for the paper's §3.1 *real-time online* requirement
   when millions of users (or an environment sweep) need placements per
   scheduler tick.
+
+* :func:`mcop_fused_solve_kernel` — the same solve, preceded in VMEM by
+  the build of each graph's weights from its environment row
+  (``backend="pallas_fused"`` of ``solve_envs``).
 
 Dense adjacency is the TPU-native layout (the paper's graphs are small —
 tens to a few thousand vertices — so a whole (n, n) matrix fits VMEM:
@@ -35,15 +34,14 @@ use the identity-mask gadget ``Σ_j eye[i,j]·v[j]`` — both plain VPU work.
 elsewhere) via ``repro.kernels.ops.default_interpret``; pass an explicit
 bool to override.
 
-Padded/dead vertices are encoded ``alive = 0`` (phase kernel) or
-``pinned = 1`` with zero weights (full kernel) and never selected (their
-score is −∞); scalars travel as (1, 1) or (1, n) 2-D arrays to keep the
-kernels TPU-lowering-friendly (2-D everywhere, no 0-D iota).
+Padded/dead vertices are encoded ``pinned = 1`` with zero weights and
+never selected (their score is −∞); scalars travel as (1, 1) or (1, n)
+2-D arrays to keep the kernels TPU-lowering-friendly (2-D everywhere, no
+0-D iota).
 
 Backend selection cheat-sheet (see also ``repro.core.mcop``):
 
 * one graph, need the per-phase trace        → ``mcop_reference`` (numpy)
-* one graph inside a jitted loop             → ``mcop_jax``
 * many graphs / env sweep, XLA               → ``core.mcop.mcop_batch``
 * many graphs, adjacency resident in VMEM    → this file's full kernel
   (``mcop_batch(..., backend="pallas")``) — loads each adjacency into
@@ -61,8 +59,9 @@ from jax.experimental import pallas as pl
 
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.ops import default_interpret
+
 __all__ = [
-    "mcop_phase_kernel",
     "mcop_stoer_wagner_kernel",
     "mcop_fused_solve_kernel",
     "default_block_graphs",
@@ -91,110 +90,7 @@ def _blocked_vmem_bytes(n: int, g: int) -> int:
 
 
 def _resolve_interpret(interpret: bool | None) -> bool:
-    if interpret is not None:
-        return interpret
-    # Deferred import: ops.py imports this module at load time.
-    from repro.kernels.ops import default_interpret
-
-    return default_interpret()
-
-
-# ======================================================================
-# Single-phase kernel (Algorithm 3) — host drives the phase loop.
-# ======================================================================
-
-
-def _phase_body(
-    adj_ref,      # (n, n) f32
-    gains_ref,    # (1, n) f32   w_local − w_cloud
-    alive_ref,    # (1, n) f32   1.0 = vertex alive in the current graph
-    src_ref,      # (1, 1) i32   anchor vertex a
-    ctot_ref,     # (1, 1) f32   C_local = Σ w_local (original graph)
-    cut_ref,      # (1, 1) f32   out: cut-of-the-phase
-    s_ref,        # (1, 1) i32   out
-    t_ref,        # (1, 1) i32   out
-    *,
-    n: int,
-):
-    adj = adj_ref[...]
-    gains = gains_ref[0, :]
-    alive = alive_ref[0, :] > 0.5
-    src = src_ref[0, 0]
-
-    n_alive = jnp.sum(alive.astype(jnp.int32))
-    idx = jax.lax.broadcasted_iota(jnp.int32, (1, n), 1)[0]
-
-    in_a0 = alive & (idx == src)
-    conn0 = adj[src, :]
-
-    def absorb(i, carry):
-        in_a, conn, s_reg, t_reg = carry
-        cand = alive & ~in_a
-        scores = jnp.where(cand, conn - gains, NEG_INF)
-        v = jnp.argmax(scores).astype(jnp.int32)
-        do = (i + 1) < n_alive          # absorb exactly n_alive−1 vertices
-        in_a = jnp.where(do, in_a | (idx == v), in_a)
-        conn = jnp.where(do, conn + adj[v, :], conn)
-        s_reg = jnp.where(do, t_reg, s_reg)
-        t_reg = jnp.where(do, v, t_reg)
-        return in_a, conn, s_reg, t_reg
-
-    _, _, s_reg, t_reg = jax.lax.fori_loop(
-        0, n - 1, absorb, (in_a0, conn0, src, src)
-    )
-
-    # Eq. 10: C_cut(A−t, t) = C_local − gains[t] + Σ_{v alive} w(e(t, v))
-    comm = jnp.sum(adj[t_reg, :] * alive.astype(jnp.float32))
-    cut_ref[0, 0] = ctot_ref[0, 0] - gains[t_reg] + comm
-    s_ref[0, 0] = s_reg
-    t_ref[0, 0] = t_reg
-
-
-def mcop_phase_kernel(
-    adj: jnp.ndarray,     # (n, n) f32 — current (possibly merged) graph
-    gains: jnp.ndarray,   # (n,) f32
-    alive: jnp.ndarray,   # (n,) bool/f32
-    src: int | jnp.ndarray,
-    c_local_total: float | jnp.ndarray,
-    *,
-    interpret: bool | None = None,
-) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Run one MinCutPhase.  Returns (cut_value, s, t).
-
-    ``interpret=None`` auto-detects: compiled on TPU, interpreter elsewhere.
-    """
-    n = adj.shape[0]
-    assert n * n * 4 <= _VMEM_BYTES, f"graph too large for single-core VMEM: n={n}"
-    body = functools.partial(_phase_body, n=n)
-    cut, s, t = pl.pallas_call(
-        body,
-        grid=(),
-        in_specs=[
-            pl.BlockSpec(adj.shape, lambda: (0, 0)),
-            pl.BlockSpec((1, n), lambda: (0, 0)),
-            pl.BlockSpec((1, n), lambda: (0, 0)),
-            pl.BlockSpec((1, 1), lambda: (0, 0)),
-            pl.BlockSpec((1, 1), lambda: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1), lambda: (0, 0)),
-            pl.BlockSpec((1, 1), lambda: (0, 0)),
-            pl.BlockSpec((1, 1), lambda: (0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((1, 1), jnp.float32),
-            jax.ShapeDtypeStruct((1, 1), jnp.int32),
-            jax.ShapeDtypeStruct((1, 1), jnp.int32),
-        ],
-        interpret=_resolve_interpret(interpret),
-    )(
-        adj.astype(jnp.float32),
-        jnp.asarray(gains, jnp.float32)[None, :],
-        jnp.asarray(alive, jnp.float32)[None, :],
-        jnp.asarray(src, jnp.int32).reshape(1, 1),
-        jnp.asarray(c_local_total, jnp.float32).reshape(1, 1),
-    )
-    return cut[0, 0], s[0, 0], t[0, 0]
+    return default_interpret() if interpret is None else interpret
 
 
 # ======================================================================

@@ -6,10 +6,6 @@ selection (interpret=True on CPU — this container — and compiled on TPU).
 
 ``flash_attention``     — drop-in for models.attention.chunked_attention.
 ``mamba_chunk_scan``    — drop-in for the scan core of ssm.mamba2_forward.
-``mcop_min_cut``        — full MCOP built on the mcop_phase kernel: the
-                          phase loop (merging, Eq. 10 bookkeeping) runs in
-                          numpy on host, each phase's O(V²) hot scan runs
-                          in the kernel.
 """
 
 from __future__ import annotations
@@ -19,16 +15,13 @@ import os
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.kernels.flash_attention import flash_attention_kernel
 from repro.kernels.mamba_scan import mamba_chunk_scan_kernel
-from repro.kernels.mcop_phase import mcop_phase_kernel
 
 __all__ = [
     "flash_attention",
     "mamba_chunk_scan",
-    "mcop_min_cut",
     "on_tpu",
     "default_interpret",
 ]
@@ -124,72 +117,3 @@ def mamba_chunk_scan(
     )
     y = y.transpose(0, 2, 3, 1, 4).reshape(b, s, h, p)
     return y, hT
-
-
-def mcop_min_cut(
-    adj: np.ndarray,
-    w_local: np.ndarray,
-    w_cloud: np.ndarray,
-    offloadable: np.ndarray,
-    *,
-    interpret: bool | None = None,
-) -> tuple[float, np.ndarray]:
-    """MCOP with the per-phase hot loop on the accelerator.
-
-    Host keeps the graph-surgery (Algorithm 1 merges, Algorithm 2 loop) in
-    numpy — that part is O(V²) total and latency-bound — while each
-    MinCutPhase's O(V²) scan runs in the Pallas kernel.  Returns
-    (min_cut, local_mask over original vertices).
-    """
-    adj = np.array(adj, np.float32)
-    w_local = np.array(w_local, np.float32)
-    w_cloud = np.array(w_cloud, np.float32)
-    n = adj.shape[0]
-    alive = np.ones(n, bool)
-    members = [{i} for i in range(n)]
-    c_total = float(w_local.sum())
-
-    # merge unoffloadables into the anchor
-    pinned = np.nonzero(~np.asarray(offloadable, bool))[0]
-    src = int(pinned[0]) if pinned.size else 0
-
-    def merge(s: int, t: int) -> None:
-        adj[s, :] += adj[t, :]
-        adj[:, s] += adj[:, t]
-        adj[s, s] = 0.0
-        adj[t, :] = 0.0
-        adj[:, t] = 0.0
-        w_local[s] += w_local[t]
-        w_cloud[s] += w_cloud[t]
-        members[s] |= members[t]
-        members[t] = set()
-        alive[t] = False
-
-    for other in pinned[1:]:
-        merge(src, int(other))
-
-    best_cut, best_cloud = np.inf, frozenset()
-    while alive.sum() > 1:
-        cut, s, t = mcop_phase_kernel(
-            jnp.asarray(adj),
-            jnp.asarray(w_local - w_cloud),
-            jnp.asarray(alive.astype(np.float32)),
-            src,
-            c_total,
-            interpret=interpret,
-        )
-        cut, s, t = float(cut), int(s), int(t)
-        if cut < best_cut:
-            best_cut = cut
-            best_cloud = frozenset(members[t])
-        if s != t:
-            merge(s, t)
-            if t == src:
-                src = s
-        else:  # degenerate single-alive-vertex phase
-            break
-
-    local_mask = np.ones(n, bool)
-    for i in best_cloud:
-        local_mask[i] = False
-    return best_cut, local_mask

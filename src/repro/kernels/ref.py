@@ -15,7 +15,6 @@ import jax.numpy as jnp
 __all__ = [
     "flash_reference",
     "mamba_chunk_scan_reference",
-    "mcop_phase_reference",
 ]
 
 NEG_INF = -2.0**30
@@ -93,32 +92,3 @@ def mamba_chunk_scan_reference(
     )
     y = ys.transpose(1, 2, 0, 3).reshape(b, h, nc, q, p)
     return y, hT
-
-
-def mcop_phase_reference(
-    adj: jnp.ndarray,     # (n, n)
-    gains: jnp.ndarray,   # (n,)
-    alive: jnp.ndarray,   # (n,) bool
-    src: int,
-    c_local_total: float,
-) -> tuple[float, int, int]:
-    """Numpy-free transcription of Algorithm 3 (used as kernel oracle)."""
-    adj = jnp.asarray(adj, jnp.float32)
-    gains = jnp.asarray(gains, jnp.float32)
-    alive = jnp.asarray(alive, bool)
-    n = adj.shape[0]
-    n_alive = int(alive.sum())
-
-    in_a = jnp.zeros(n, bool).at[src].set(True) & alive
-    conn = adj[src]
-    s_reg = t_reg = int(src)
-    for i in range(n_alive - 1):
-        cand = alive & ~in_a
-        scores = jnp.where(cand, conn - gains, NEG_INF)
-        v = int(jnp.argmax(scores))
-        in_a = in_a.at[v].set(True)
-        conn = conn + adj[v]
-        s_reg, t_reg = t_reg, v
-    comm = float((adj[t_reg] * alive).sum())
-    cut = float(c_local_total) - float(gains[t_reg]) + comm
-    return cut, s_reg, t_reg

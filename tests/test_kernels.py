@@ -5,10 +5,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import brute_force, mcop_reference, paper_example_graph, random_wcg
-from repro.kernels import flash_attention, mamba_chunk_scan, mcop_min_cut, ref
+from repro.core import brute_force, mcop, mcop_reference, paper_example_graph, random_wcg
+from repro.kernels import flash_attention, mamba_chunk_scan, ref
 from repro.kernels.flash_attention import flash_attention_kernel
-from repro.kernels.mcop_phase import mcop_phase_kernel
 
 
 # ----------------------------------------------------------------------
@@ -148,45 +147,33 @@ def test_mamba_kernel_matches_model_ssd_path():
 
 
 # ----------------------------------------------------------------------
-# MCOP phase kernel
+# MCOP full kernel
 # ----------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("seed", range(8))
-def test_mcop_phase_kernel_matches_reference(seed):
-    g = random_wcg(9, rng=np.random.default_rng(seed))
-    gains = g.w_local - g.w_cloud
-    alive = np.ones(g.n, bool)
-    src = int(np.nonzero(~g.offloadable)[0][0])
-    cut_k, s_k, t_k = mcop_phase_kernel(
-        jnp.asarray(g.adj, jnp.float32), gains, alive, src, g.w_local.sum()
-    )
-    cut_r, s_r, t_r = ref.mcop_phase_reference(
-        g.adj, gains, alive, src, g.w_local.sum()
-    )
-    assert float(cut_k) == pytest.approx(cut_r, rel=1e-5)
-    assert (int(s_k), int(t_k)) == (s_r, t_r)
-
-
-@pytest.mark.parametrize("n,seed", [(5, 0), (8, 1), (12, 2), (15, 3), (10, 4)])
+@pytest.mark.parametrize(
+    "n,seed",
+    [(5, 100), (8, 101), (12, 102), (15, 103), (10, 104)]
+    + [(9, seed) for seed in range(8)],
+)
 def test_mcop_kernel_full_algorithm_matches_reference(n, seed):
-    """The kernel-backed MCOP is the SAME algorithm as mcop_reference —
+    """The Pallas full kernel is the SAME algorithm as mcop_reference —
     same (possibly suboptimal, see test_mcop_property) cut, same mask."""
-    g = random_wcg(n, rng=np.random.default_rng(seed + 100))
-    cut, mask = mcop_min_cut(g.adj, g.w_local, g.w_cloud, g.offloadable)
+    g = random_wcg(n, rng=np.random.default_rng(seed))
+    res = mcop(g, backend="pallas")
     ref_res = mcop_reference(g)
-    assert cut == pytest.approx(ref_res.min_cut, rel=1e-5)
-    assert (mask == ref_res.local_mask).all()
-    assert g.total_cost(mask) == pytest.approx(cut, rel=1e-5)
+    assert res.min_cut == pytest.approx(ref_res.min_cut, rel=1e-5)
+    assert (res.local_mask == ref_res.local_mask).all()
+    assert g.total_cost(res.local_mask) == pytest.approx(res.min_cut, rel=1e-5)
     # never better than the true optimum (up to the kernel's f32 rounding)
-    assert cut >= brute_force(g).cost * (1 - 1e-5) - 1e-4
+    assert res.min_cut >= brute_force(g).cost * (1 - 1e-5) - 1e-4
 
 
 def test_mcop_kernel_paper_example():
     g = paper_example_graph()
-    cut, mask = mcop_min_cut(g.adj, g.w_local, g.w_cloud, g.offloadable)
-    assert cut == pytest.approx(22.0)
-    assert (mask == mcop_reference(g).local_mask).all()
+    res = mcop(g, backend="pallas")
+    assert res.min_cut == pytest.approx(22.0)
+    assert (res.local_mask == mcop_reference(g).local_mask).all()
 
 
 # ----------------------------------------------------------------------

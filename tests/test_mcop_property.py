@@ -17,8 +17,8 @@ The properties below are therefore the ones that actually hold:
   * the exact solver is monotone in bandwidth and hits the textbook
     limits (B→∞ / B→0).
 
-The optimality-gap distribution itself is quantified in
-``benchmarks/optimality_gap.py`` and reported in EXPERIMENTS.md.
+The exact-rate bound itself is asserted by
+``test_mcop_exact_rate_on_adversarial_distribution`` below.
 """
 
 import numpy as np
@@ -35,7 +35,7 @@ from repro.core import (
     linear_graph,
     loop_graph,
     maxflow_optimal,
-    mcop_jax,
+    mcop,
     mcop_reference,
     mesh_graph,
     no_offloading,
@@ -97,7 +97,7 @@ def test_mcop_bounds_and_self_consistency_smoke(seed):
 def test_jax_backend_matches_reference_smoke(seed):
     g = _smoke_wcg(100 + seed)
     ref = mcop_reference(g)
-    jx = mcop_jax(g)
+    jx = mcop(g, backend="jax")
     assert jx.min_cut == pytest.approx(ref.min_cut, rel=1e-5, abs=1e-4)
     assert g.total_cost(jx.local_mask) == pytest.approx(ref.min_cut, rel=1e-5, abs=1e-4)
 
@@ -133,7 +133,7 @@ def test_maxflow_oracle_agrees_with_brute_force(g):
 def test_jax_backend_matches_reference(g):
     """The jittable MCOP implements the same algorithm, bit-for-bit-ish."""
     ref = mcop_reference(g)
-    jx = mcop_jax(g)
+    jx = mcop(g, backend="jax")
     assert jx.min_cut == pytest.approx(ref.min_cut, rel=1e-5, abs=1e-4)
     assert g.total_cost(jx.local_mask) == pytest.approx(ref.min_cut, rel=1e-5, abs=1e-4)
 

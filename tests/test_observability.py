@@ -13,9 +13,9 @@ The PR-8 acceptance suite.  The headline contracts:
   same-tick ``fault`` event — the ``tools/tracequery.py --audit`` CI
   gate, exercised here end to end through a scripted fault schedule.
 
-The enabled-path throughput budget (1.15× of detached) is gated in
-``benchmarks/broker.py`` (``broker/traced_*``), not here — wall-clock
-ratios don't belong in tier-1.
+What the enabled path costs in time is for the chip benchmark
+(``bench/``) to measure, not here — wall-clock ratios don't belong in
+tier-1.
 """
 
 import dataclasses

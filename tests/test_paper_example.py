@@ -18,12 +18,11 @@ from repro.core import (
     full_offloading,
     linear_graph,
     maxflow_optimal,
-    mcop_jax,
+    mcop,
     mcop_reference,
     no_offloading,
     paper_example_graph,
 )
-from repro.kernels import mcop_min_cut
 
 
 @pytest.fixture(scope="module")
@@ -83,14 +82,14 @@ def test_gui_comparison_costs(g):
 
 def test_all_backends_agree_on_paper_example(g):
     ref = mcop_reference(g)
-    jx = mcop_jax(g)
+    jx = mcop(g, backend="jax")
     bf = brute_force(g)
     mf = maxflow_optimal(g)
     bb = branch_and_bound(g)
-    kcut, kmask = mcop_min_cut(g.adj, g.w_local, g.w_cloud, g.offloadable)
-    for cost in (jx.min_cut, bf.cost, mf.cost, bb.cost, kcut):
+    kx = mcop(g, backend="pallas")
+    for cost in (jx.min_cut, bf.cost, mf.cost, bb.cost, kx.min_cut):
         assert cost == pytest.approx(22.0)
-    assert (kmask == ref.local_mask).all()
+    assert (kx.local_mask == ref.local_mask).all()
     assert (bf.local_mask == ref.local_mask).all()
 
 

@@ -19,7 +19,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
 
-from repro.core.mcop import _mcop_jax_batch
+from repro.core.mcop import _mcop_batch_jit
 from repro.core.mcop_shard import _sharded_dispatch
 from repro.kernels.mcop_phase import (
     FUSED_MODEL_KINDS,
@@ -82,7 +82,7 @@ def test_stoer_wagner_kernel_compiles(one_chip, n):
 def test_jax_batch_solver_compiles(one_chip, n):
     adj, wl, wc = _shapes(one_chip, (BATCH, n, n), (BATCH, n), (BATCH, n))
     (pin,) = _shapes(one_chip, (BATCH, n), dtype=jnp.bool_)
-    compiled = _mcop_jax_batch.lower(adj, wl, wc, pin).compile()
+    compiled = _mcop_batch_jit.lower(adj, wl, wc, pin).compile()
     assert compiled.memory_analysis().output_size_in_bytes >= BATCH * (n + 4)
 
 

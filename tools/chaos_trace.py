@@ -4,8 +4,8 @@
     PYTHONPATH=src python tools/chaos_trace.py --out trace.jsonl \
         [--chrome trace.json] [--rate 0.10] [--steps 12] [--users 16]
 
-Replays the ``benchmarks/faults.py`` workload — a seeded multi-user
-request stream through a resilient ``OffloadBroker`` — at the given
+Replays a seeded multi-user request stream through a resilient
+``OffloadBroker`` at the given
 fault rate with a :class:`repro.obs.trace.Tracer` and
 :class:`repro.obs.metrics.MetricsRegistry` attached, then exports the
 span trace.  Broker and tracer share one
