@@ -17,22 +17,32 @@ Backend-selection story — when each wins:
   want the trace, f64 arithmetic, or are debugging; it is the semantic
   oracle everything else is tested against.
 
-* :func:`mcop_batch` (``backend="jax"``) — the throughput path, and the
-  one every served flush takes.  Pads a heterogeneous list of graphs
-  into static shape *buckets* (default 16/64/128/256 vertices) and
-  ``vmap``s a jitted dense masked Stoer–Wagner per bucket, so N
-  environment points or N concurrent requests compile to ONE XLA program
-  per bucket rather than N traces, and execute as one dispatch.  Amortizes
-  dispatch overhead and keeps the batch resident on-device; this is what
-  ``AdaptiveController.sweep`` and the placement tier sweep call.
+* :func:`mcop_batch` (``backend="jax"``, the default) — the throughput
+  path, and the one every served flush takes.  Pads a heterogeneous list
+  of graphs into static shape *buckets* (default 16/64/128/256 vertices)
+  and solves each bucket as ONE device dispatch, with the solver that
+  suits its shape, chosen by :func:`_solver_for` from what the code
+  observes — the platform, the bucket and the solver dtype:
 
-* ``mcop_batch(..., backend="pallas")`` — same bucketing, but each bucket
-  runs ``repro.kernels.mcop_phase.mcop_stoer_wagner_kernel``: the full
-  |V|−1-phase solve (merges included) inside one Pallas kernel with a
-  grid dimension over the batch, so the adjacency is loaded HBM→VMEM once
-  per solve.  Its speed against the XLA path is not measured on a chip;
-  on CPU it falls back to interpret mode (correct but slow — benchmark
-  numbers there are indicative only).
+  - on a TPU, for float32 buckets of ``LOCKSTEP_MIN_BUCKET`` (64)
+    vertices or more, ``_mcop_batch_impl_lockstep``: the Pallas kernel of
+    ``repro.kernels.mcop_phase`` that solves all of a flush's graphs in
+    lockstep, so the chain of Σ(n_alive − 1) dependent MTCV steps (~3,900
+    for a 90-vertex graph) runs once per flush inside VMEM;
+  - otherwise ``_mcop_batch_jit``, a ``vmap`` of the jitted dense masked
+    Stoer–Wagner (XLA ``while`` loops): small buckets, whose chain is at
+    most 120 steps (bucket 16), the CPU, and float64 under x64.
+
+  Either way one program per (bucket, batch).  This is what
+  ``AdaptiveController.sweep``, the placement tier sweep, the broker and
+  the session engine call; a tracer's ``solve.wait`` span names the
+  solver that ran (``solver="lockstep"`` or ``"xla"``).
+
+* ``mcop_batch(..., backend="pallas")`` — the lockstep kernel for every
+  bucket, on any platform (interpret mode off a TPU: correct but slow).
+  Where the default path runs the kernel too, the two backends share one
+  failure domain: the broker's circuit breaker (pallas → jax →
+  reference) then serves a kernel fault from the numpy reference.
 
 * :func:`mcop_batch` also accepts a :class:`~repro.core.graph.WCGBatch`
   directly — consumers that already hold stacked tensors (the cost
@@ -55,6 +65,7 @@ any phase cut; graphs with no unoffloadable vertex are anchored at vertex
 from __future__ import annotations
 
 import dataclasses
+import functools
 from collections import OrderedDict
 from typing import Sequence
 
@@ -353,6 +364,83 @@ def _mcop_batch_impl(adj, w_local, w_cloud, pinned):
 _mcop_batch_jit = jax.jit(jax.vmap(_mcop_batch_impl))
 
 
+@functools.partial(jax.jit, static_argnames=("group", "interpret"))
+def _mcop_batch_impl_lockstep(adj, w_local, w_cloud, pinned, *, group, interpret):
+    """The lockstep Pallas solve of a (k, m[, m]) bucket, one program per
+    (k, m): float32 conversion, inert all-pinned graphs padding the batch
+    to whole groups, the pinned fold of :func:`_fold_pinned` (as the XLA
+    path folds), ``kernels.mcop_phase.lockstep_phases`` and the crop, all
+    inside this jit.  Returns ``(cuts (k,), local masks (k, m) bool)``."""
+    from repro.kernels.mcop_phase import lockstep_phases  # deferred: kernel deps
+
+    f32 = jnp.float32
+    k, m = w_local.shape
+    pad = ((0, (-k) % group), (0, 0))
+    adj = jnp.pad(jnp.asarray(adj, f32), (*pad, (0, 0)))
+    wl = jnp.pad(jnp.asarray(w_local, f32), pad)
+    wc = jnp.pad(jnp.asarray(w_cloud, f32), pad)
+    pin = jnp.pad(jnp.asarray(pinned).astype(bool), pad, constant_values=True)
+    c_local_total = wl.sum(axis=1)
+    adj, wl, wc, alive, src = jax.vmap(_fold_pinned)(adj, wl, wc, pin)
+    label = jnp.where(pin | ~alive, src[:, None], jnp.arange(m))
+    cuts, masks = lockstep_phases(
+        adj, wl, wc, alive.astype(f32), label.astype(f32), src.astype(f32),
+        c_local_total, group=group, interpret=interpret,
+    )
+    return cuts[:k], masks[:k] > 0.5
+
+
+def mcop_stoer_wagner_kernel(
+    adj, w_local, w_cloud, pinned, *, interpret: bool | None = None,
+    block_graphs: int | None = None,
+):
+    """Solve a (B, n, n) batch with the lockstep Pallas kernel (the
+    ``"pallas"`` backend, and ``"jax"`` where :func:`_solver_for` picks
+    it): :func:`_mcop_batch_impl_lockstep` with groups of
+    ``block_graphs`` graphs (``None`` = as many as VMEM holds,
+    ``kernels.mcop_phase.lockstep_group``).  Returns ``(min_cuts (B,),
+    local_masks (B, n) bool)``: the arithmetic of ``_mcop_batch_impl`` in
+    float32 with the same tie-breaking, whatever the grouping.  Padded
+    vertices must be pinned with zero weights and zero incident edges.
+    ``interpret=None`` compiles on a TPU and interprets elsewhere."""
+    from repro.kernels.mcop_phase import lockstep_group  # deferred: kernel deps
+    from repro.kernels.ops import default_interpret
+
+    assert adj.ndim == 3, f"expected (B, n, n) batch, got {adj.shape}"
+    return _mcop_batch_impl_lockstep(
+        adj, w_local, w_cloud, pinned,
+        group=lockstep_group(adj.shape[0], adj.shape[-1], block_graphs),
+        interpret=default_interpret() if interpret is None else interpret,
+    )
+
+
+# The smallest bucket whose chain of dependent MTCV steps (Σ(n_alive − 1):
+# at most 120 at bucket 16, at least 2,016 at 64) is worth a Pallas kernel.
+LOCKSTEP_MIN_BUCKET = 64
+
+
+def _solver_for(platform: str, m: int, dtype) -> str:
+    """The solver the default path (``backend="jax"``) runs for an
+    m-vertex bucket in ``dtype`` on ``platform``: ``"lockstep"`` (the
+    Pallas kernel) on a TPU for float32 buckets of ``LOCKSTEP_MIN_BUCKET``
+    or more, ``"xla"`` (the vmapped ``_mcop_batch_impl``) otherwise, so
+    x64 keeps float64 and small buckets keep their program."""
+    lockstep = (
+        platform == "tpu"
+        and m >= LOCKSTEP_MIN_BUCKET
+        and np.dtype(dtype) == np.float32
+    )
+    return "lockstep" if lockstep else "xla"
+
+
+def _solver_name(backend: str, m: int, dtype) -> str:
+    """What solves a bucket for ``backend``: the ``solver`` attribute of
+    ``solve.wait`` and label of the dispatch metrics."""
+    if backend == "jax":
+        return _solver_for(jax.default_backend(), m, dtype)
+    return {"pallas": "lockstep", "pallas_fused": "fused"}.get(backend, backend)
+
+
 def _bucket_size(n: int, buckets: Sequence[int]) -> int:
     for b in sorted(buckets):
         if n <= b:
@@ -393,20 +481,20 @@ def _solver_dtype(backend: str):
 
 def _dispatch_arrays(adj, wl, wc, pin, backend: str, interpret: bool | None):
     """One device dispatch over pre-packed (b, m[, m]) tensors."""
-    if backend == "jax":
+    if _solver_name(backend, adj.shape[-1], adj.dtype) == "xla":
         return _mcop_batch_jit(adj, wl, wc, pin)
-    # deferred: keep core importable without pulling kernel deps
-    from repro.kernels.mcop_phase import mcop_stoer_wagner_kernel
-
     return mcop_stoer_wagner_kernel(adj, wl, wc, pin, interpret=interpret)
 
 
-def _solve_wait(tracer):
+def _solve_wait(tracer, solver: str):
     """Span over the host's wait for a flush's device results
     (``solve.wait``, a child of the caller's ``stage.solve_flush``): it
     ends once the results are on the host, after the solve program has
-    ended on the device."""
-    return tracer.span("solve.wait") if tracer is not None else NULL_SPAN
+    ended on the device.  ``solver`` names what solved it
+    (:func:`_solver_name`)."""
+    if tracer is None:
+        return NULL_SPAN
+    return tracer.span("solve.wait", solver=solver)
 
 
 def _solve_wcg_batch(
@@ -448,7 +536,7 @@ def _solve_wcg_batch(
             backend,
             interpret,
         )
-        with _solve_wait(tracer):
+        with _solve_wait(tracer, _solver_name(backend, batch.m, dtype)):
             cuts, masks = jax.device_get((cuts, masks))  # one host sync
     return [
         MCOPResult(
@@ -476,9 +564,10 @@ def mcop_batch(
         heterogeneous sizes), or a single
         :class:`~repro.core.graph.WCGBatch` of K graphs padded to one
         static shape ``(k, m[, m])``.
-      backend:  ``"jax"`` (bucketed ``vmap`` of the jitted solver),
-        ``"pallas"`` (one grid-over-batch kernel call per bucket), or
-        ``"reference"`` (loops the numpy oracle — testing/parity).
+      backend:  ``"jax"`` (the default solver for each bucket's shape,
+        :func:`_solver_for`), ``"pallas"`` (the lockstep kernel for every
+        bucket), or ``"reference"`` (loops the numpy oracle —
+        testing/parity).
       buckets:  static shape buckets; each graph is zero-padded to the
         smallest bucket ≥ its vertex count and each bucket is ONE device
         dispatch.  Ignored for a ``WCGBatch`` (its padded shape *is* the
@@ -492,7 +581,8 @@ def mcop_batch(
         single-device dispatch, a ``Mesh`` shards over exactly that
         fleet.  Results are bit-identical either way.
       tracer:   optional :class:`~repro.obs.trace.Tracer` — the wait for
-        each bucket's results is a ``solve.wait`` span; on the sharded
+        each bucket's results is a ``solve.wait`` span (attribute
+        ``solver``: ``"lockstep"`` or ``"xla"``); on the sharded
         path a ``solve.shard_pack`` span covers the host's packing before
         it, and the wait holds one ``solve.shard`` span per device (shard
         index, device count, row count).
@@ -543,7 +633,7 @@ def mcop_batch(
         else:
             adj, wl, wc, pin = (jnp.asarray(a) for a in packed)
             cuts, masks = _dispatch_arrays(adj, wl, wc, pin, backend, interpret)
-            with _solve_wait(tracer):
+            with _solve_wait(tracer, _solver_name(backend, m, dtype)):
                 cuts, masks = jax.device_get((cuts, masks))  # one host sync
         for row, i in enumerate(idxs):
             results[i] = MCOPResult(
@@ -606,10 +696,8 @@ def _fused_solver(model, backend: str, interpret: bool | None, mesh=None):
             def fused(t_local, data_in, data_out, pinned, env):
                 wl, wc, adj = model.batch_weights(t_local, data_in, data_out, env)
                 pin = jnp.broadcast_to(pinned[None, :], wl.shape)
-                if backend == "jax":
+                if _solver_name(backend, adj.shape[-1], adj.dtype) == "xla":
                     return jax.vmap(_mcop_batch_impl)(adj, wl, wc, pin)
-                from repro.kernels.mcop_phase import mcop_stoer_wagner_kernel
-
                 return mcop_stoer_wagner_kernel(adj, wl, wc, pin, interpret=interpret)
 
         if mesh is None:
@@ -652,7 +740,8 @@ def solve_envs(
         an :class:`~repro.core.cost_models.EnvArrays` holding them as six
         (k,) columns (the batched session engine's form); six scalars per
         environment are all that crosses the host boundary.
-      backend: ``"jax"`` / ``"pallas"`` for the fused program,
+      backend: ``"jax"`` (the default solver for the bucket, as in
+        :func:`mcop_batch`) / ``"pallas"`` for the fused program,
         ``"pallas_fused"`` for the VMEM-resident kernel that builds each
         environment's WCG weights in-kernel immediately before its solve
         (built-in cost-model kinds only), or ``"reference"`` to route
@@ -663,7 +752,7 @@ def solve_envs(
       metrics: optional :class:`~repro.obs.metrics.MetricsRegistry` —
         when given, each call counts one ``solve_envs_dispatches`` and
         times the dispatch into ``solve_envs_duration_s``, both labeled
-        ``(backend, bucket, devices)``.  ``None`` (default) adds no work
+        ``(backend, bucket, devices, solver)``.  ``None`` (default) adds no work
         and no clock reads.
       mesh:    solver-fleet routing (``repro.core.mcop_shard``):
         ``None`` auto-shards the K environments across every device the
@@ -703,18 +792,18 @@ def solve_envs(
     validate_env_finite(envs)
     from repro.core.mcop_shard import resolve_mesh, solver_shards  # deferred
 
+    if backend not in ("reference", "jax", "pallas", "pallas_fused"):
+        raise ValueError(f"unknown MCOP batch backend: {backend!r}")
     use_mesh = None if backend == "reference" else resolve_mesh(mesh)
     devices = 1 if use_mesh is None else solver_shards(use_mesh)
+    dtype = _solver_dtype(backend)
+    n = profile.n
+    m = _bucket_size(n, buckets)
+    solver = _solver_name(backend, m, dtype)
     if metrics is not None:
-        bucket = _bucket_size(profile.n, buckets)
-        metrics.counter(
-            "solve_envs_dispatches",
-            backend=backend, bucket=bucket, devices=devices,
-        ).inc()
-        timer = metrics.timer(
-            "solve_envs_duration_s",
-            backend=backend, bucket=bucket, devices=devices,
-        )
+        labels = dict(backend=backend, bucket=m, devices=devices, solver=solver)
+        metrics.counter("solve_envs_dispatches", **labels).inc()
+        timer = metrics.timer("solve_envs_duration_s", **labels)
     else:
         timer = NULL_SPAN
     if backend == "reference":
@@ -723,11 +812,6 @@ def solve_envs(
                 mcop_reference(g)
                 for g in model.build_batch(profile, envs).to_wcgs()
             ]
-    if backend not in ("jax", "pallas", "pallas_fused"):
-        raise ValueError(f"unknown MCOP batch backend: {backend!r}")
-    dtype = _solver_dtype(backend)
-    n = profile.n
-    m = _bucket_size(n, buckets)
 
     # Environment-independent profile tensors, zero-padded to the bucket;
     # padding is pinned and a pin-free profile anchors at vertex 0 (the
@@ -762,6 +846,7 @@ def solve_envs(
                 env_cols,
                 mesh=use_mesh,
                 tracer=tracer,
+                solver=solver,
             )
         else:
             cuts, masks = fn(
@@ -771,7 +856,7 @@ def solve_envs(
                 jnp.asarray(pinned),
                 env_cols,
             )
-            with _solve_wait(tracer):
+            with _solve_wait(tracer, solver):
                 cuts, masks = jax.device_get((cuts, masks))  # one host sync
     return [
         MCOPResult(min_cut=float(cuts[i]), local_mask=masks[i, :n].copy(), phases=[])

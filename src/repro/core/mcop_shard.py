@@ -256,9 +256,9 @@ def sharded_dispatch_arrays(
         cuts_sh, masks_sh = fn(
             adj[plan.perm], wl[plan.perm], wc[plan.perm], pin[plan.perm]
         )
-    from repro.core.mcop import _solve_wait  # deferred: cycle
+    from repro.core.mcop import _solve_wait, _solver_name  # deferred: cycle
 
-    with _solve_wait(tracer):
+    with _solve_wait(tracer, _solver_name(backend, m, adj.dtype)):
         _emit_shard_spans(tracer, plan, (cuts_sh, masks_sh), stage="solve")
         cuts_sh, masks_sh = jax.device_get((cuts_sh, masks_sh))
     return cuts_sh[plan.inverse][: plan.k], masks_sh[plan.inverse][: plan.k]
@@ -312,6 +312,7 @@ def sharded_solve_envs_call(
     *,
     mesh: Mesh,
     tracer=None,
+    solver: str,
 ):
     """Run a :func:`sharded_fused_solver` program over K environments.
 
@@ -335,7 +336,7 @@ def sharded_solve_envs_call(
         cuts_sh, masks_sh = fn(t_local, data_in, data_out, pinned, env_sh)
     from repro.core.mcop import _solve_wait  # deferred: cycle
 
-    with _solve_wait(tracer):
+    with _solve_wait(tracer, solver):
         _emit_shard_spans(tracer, plan, (cuts_sh, masks_sh), stage="solve_envs")
         cuts_sh, masks_sh = jax.device_get((cuts_sh, masks_sh))
     return cuts_sh[plan.inverse][: plan.k], masks_sh[plan.inverse][: plan.k]

@@ -5,8 +5,9 @@ in ``ops.py``:
 
 * ``flash_attention`` — online-softmax attention (causal/full/window, GQA)
 * ``mamba_chunk_scan`` — Mamba2 SSD chunked selective scan
-* ``mcop_stoer_wagner_kernel`` — full batched MCOP: all phases + merges in
-  one kernel invocation, grid over graphs (see ``core.mcop.mcop_batch``)
+* ``lockstep_phases`` — full batched MCOP: all phases + merges of a group
+  of graphs in one lockstep chain (``core.mcop.mcop_stoer_wagner_kernel``
+  folds the pins and calls it; see ``core.mcop.mcop_batch``)
 
 ``default_interpret`` picks interpret-vs-compiled once per process from the
 JAX backend; all kernel wrappers accept ``interpret=None`` to mean "auto".
@@ -18,13 +19,13 @@ from repro.kernels.ops import (
     mamba_chunk_scan,
     on_tpu,
 )
-from repro.kernels.mcop_phase import mcop_stoer_wagner_kernel
+from repro.kernels.mcop_phase import lockstep_phases
 from repro.kernels import ref
 
 __all__ = [
     "flash_attention",
     "mamba_chunk_scan",
-    "mcop_stoer_wagner_kernel",
+    "lockstep_phases",
     "default_interpret",
     "on_tpu",
     "ref",

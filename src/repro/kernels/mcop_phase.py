@@ -1,52 +1,58 @@
 """Pallas TPU kernels for MCOP (paper Algorithms 1–3) — full and fused solvers.
 
-Two kernels, one memory story:
+* :func:`lockstep_phases` — the FULL modified Stoer–Wagner for a batch
+  of graphs, solved in lockstep.  Its caller, one jitted program per
+  (B, n) (``core.mcop._mcop_batch_impl_lockstep``, entered through
+  ``core.mcop.mcop_stoer_wagner_kernel``), folds the unoffloadable
+  vertices into the anchor in XLA; then one ``pallas_call`` runs every
+  phase and every Algorithm-1 merge of a *group* of graphs: each loop
+  iteration is one Most-Tightly-Connected-Vertex step of every graph at
+  once, so the chain of Σ(n_alive − 1) dependent steps (~3,900 for a
+  90-vertex graph) runs once per group, not once per graph, and no step
+  is an XLA loop iteration.  A group is as many graphs as VMEM holds (88
+  at n = 128: a whole flush), so the group's adjacencies are loaded into
+  VMEM once and merged there in place.  This is what the default path
+  runs on a TPU for float32 buckets of 64 vertices or more (see
+  ``core.mcop._solver_for``), and ``backend="pallas"`` everywhere.
 
-* :func:`mcop_stoer_wagner_kernel` — the FULL modified Stoer–Wagner in a
-  single kernel invocation, batched over graphs.  All |V|−1 phases, the
-  Algorithm-1 merges of (s, t), and the initial fold of unoffloadable
-  vertices into the anchor run inside the kernel body, so the adjacency is
-  loaded into VMEM exactly once per solve.  A grid dimension over the
-  batch lets one ``pallas_call`` partition B independent graphs — the
-  throughput shape for the paper's §3.1 *real-time online* requirement
-  when millions of users (or an environment sweep) need placements per
-  scheduler tick.
+* :func:`mcop_fused_solve_kernel` — one graph's solve at a time
+  (:func:`_solve_graph`), preceded in VMEM by the build of its weights
+  from its environment row (``backend="pallas_fused"`` of
+  ``solve_envs``).
 
-* :func:`mcop_fused_solve_kernel` — the same solve, preceded in VMEM by
-  the build of each graph's weights from its environment row
-  (``backend="pallas_fused"`` of ``solve_envs``).
+Lockstep layout: per-graph vectors (scores, ``conn``, ``in_a``, ``alive``,
+the labels, the weights) are (g, n) tiles with one graph per sublane row,
+and per-graph scalars (s, t, the anchor, the best cut) are (g, 1)
+columns.  One step, for every graph at once:
 
-Dense adjacency is the TPU-native layout (the paper's graphs are small —
-tens to a few thousand vertices — so a whole (n, n) matrix fits VMEM:
-n = 1024 f32 is 4 MB against the ~16 MB/core budget; the wrappers enforce
-the bound).  The phase hot loop is the Most-Tightly-Connected-Vertex scan:
+    Δ(v)  = conn(v) − [w_local(v) − w_cloud(v)]   over v ∉ A   (g, n)
+    v*    = first argmax Δ per row                 (lane max, then min index)
+    conn += adj[j, v*_j, :]                        (one-hot row read)
 
-    repeat |V|−1 times:
-        Δ(v)  = conn(v) − [w_local(v) − w_cloud(v)]   over v ∉ A
-        v*    = argmax Δ                               (VPU masked max)
-        conn += adj[v*]                                (VPU row add)
-
-The full kernel avoids dynamic row gathers and transposes entirely: rows
-are extracted with one-hot masked reductions, and row↔column vector moves
-use the identity-mask gadget ``Σ_j eye[i,j]·v[j]`` — both plain VPU work.
+Graphs of different true size in one bucket run to the group's largest
+step count; a finished graph is masked, as vmap's while rule masks it.
+The row read and the merge are the only per-graph work: a tile of 8
+graphs at a time, each graph's (n, n) block masked by a one-hot row
+selector and reduced over its rows on the VPU, with no dynamic gather
+and no transpose (a row becomes a column through the identity mask
+``Σ_j eye[i,j]·v[j]``, which the merge needs once per phase).
 
 ``interpret`` defaults to auto-detection (compiled on TPU, interpreter
 elsewhere) via ``repro.kernels.ops.default_interpret``; pass an explicit
 bool to override.
 
 Padded/dead vertices are encoded ``pinned = 1`` with zero weights and
-never selected (their score is −∞); scalars travel as (1, 1) or (1, n)
-2-D arrays to keep the kernels TPU-lowering-friendly (2-D everywhere, no
-0-D iota).
+never selected (their score is −∞); everything stays 2-D (or a leading
+batch axis over 2-D tiles) to keep the kernels TPU-lowering-friendly.
 
 Backend selection cheat-sheet (see also ``repro.core.mcop``):
 
 * one graph, need the per-phase trace        → ``mcop_reference`` (numpy)
-* many graphs / env sweep, XLA               → ``core.mcop.mcop_batch``
-* many graphs, adjacency resident in VMEM    → this file's full kernel
-  (``mcop_batch(..., backend="pallas")``) — loads each adjacency into
-  VMEM once per solve; how it compares with the XLA path is not measured
-  on a chip.
+* many graphs / env sweep                    → ``core.mcop.mcop_batch`` /
+  ``solve_envs``: this file's lockstep kernel on a TPU at buckets ≥ 64
+  in float32, the vmapped XLA solver otherwise (bucket 16, CPU, x64)
+* many environments, weights built in VMEM   → the fused kernel
+  (``backend="pallas_fused"``)
 """
 
 from __future__ import annotations
@@ -62,7 +68,8 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.ops import default_interpret
 
 __all__ = [
-    "mcop_stoer_wagner_kernel",
+    "lockstep_phases",
+    "lockstep_group",
     "mcop_fused_solve_kernel",
     "default_block_graphs",
     "FUSED_MODEL_KINDS",
@@ -76,8 +83,9 @@ POS_INF = 1e30
 
 # VMEM bound: adjacency + vectors must fit on-core alongside double-buffers.
 _VMEM_BYTES = 12 * 2**20
-# n²-sized f32 arrays the solve body keeps live at once (adj, members,
-# eye, the two index iotas and the merge temporaries).
+# n²-sized f32 arrays a solve body keeps live besides its adjacency
+# blocks (the index iotas, eye, members, the masks and temporaries of
+# the row reads and merges).
 _WORK_ARRAYS = 8
 # The scoped-VMEM limit the kernels compile against: the budget above
 # plus headroom for the vectors, the loop state and Mosaic's own scratch.
@@ -94,7 +102,7 @@ def _resolve_interpret(interpret: bool | None) -> bool:
 
 
 # ======================================================================
-# Full solver kernel — all phases + merges, one VMEM load, batch grid.
+# One graph's solve in VMEM — the fused kernel's body.
 # ======================================================================
 
 
@@ -103,10 +111,9 @@ def _solve_graph(adj, wl, wc, pin, *, n: int):
 
     Args are VALUES already resident in VMEM (not refs): ``adj`` (n, n)
     f32, ``wl``/``wc`` (1, n) f32, ``pin`` (1, n) bool.  Returns
-    ``(best_cut (1, 1) f32, local_mask (1, n) f32)``.  Factoring the
-    solve out of the pallas body lets one program invocation solve a
-    whole *block* of graphs (grid tuning) and lets the fused variant
-    build the WCG weights in VMEM immediately before calling this.
+    ``(best_cut (1, 1) f32, local_mask (1, n) f32)``.  The fused kernel
+    builds each graph's WCG weights in VMEM immediately before calling
+    this, a block of graphs back to back per grid step.
 
     Everything stays a 2-D vector, as Mosaic requires: vertex indices are
     (1, 1) f32 vectors compared against f32 iotas (exact for n < 2**24),
@@ -244,79 +251,256 @@ def _solve_graph(adj, wl, wc, pin, *, n: int):
     return best_cut, 1.0 - best_cloud
 
 
-def _sw_block_body(
-    adj_ref,   # (g, n, n) f32 — a block of g graphs
-    wl_ref,    # (g, 1, n) f32
-    wc_ref,    # (g, 1, n) f32
-    pin_ref,   # (g, 1, n) f32  1.0 = unoffloadable (pinned to local tier)
-    cut_ref,   # (g, 1, 1) f32  out: min over phases of Eq. 10
-    mask_ref,  # (g, 1, n) f32  out: 1.0 = execute locally
+# ======================================================================
+# Lockstep full solver — every graph of a group advances one MTCV step per
+# loop iteration, so the chain of dependent steps runs once per group.
+# ======================================================================
+
+
+def _lockstep_body(
+    nmax_ref,   # SMEM (groups,) i32: most alive vertices of any graph per group
+    adj_ref,    # (g, m, m) f32 block: adjacencies, pinned folded; merged in place
+    wl_ref,     # (g, m) f32 block: w_local, folded
+    wc_ref,     # (g, m) f32 block: w_cloud, folded
+    alive_ref,  # (g, m) f32 block: 1.0 = vertex alive after the fold
+    label_ref,  # (g, m) f32 block: each vertex's representative
+    src_ref,    # (g, 1) f32 block: the anchor
+    ctot_ref,   # (g, 1) f32 block: C_local, invariant under merging
+    cut_ref,    # (g, 1) f32 out: min over phases of Eq. 10
+    mask_ref,   # (g, m) f32 out: 1.0 = execute locally
+    idx_ref,    # VMEM scratch (g, m): a vertex index per graph, along lanes
+    s_ref,      # VMEM scratch (g, m): a merge's survivor per graph, along lanes
+    row_ref,    # VMEM scratch (g, m): adjacency rows read at idx_ref
     *,
-    n: int,
+    m: int,
     g: int,
 ):
-    """Solve the g graphs of this grid step back-to-back in VMEM.
+    """Algorithm 2's phases for a group of g graphs, in lockstep.
 
-    ``g == 1`` reproduces the historical one-graph-per-program grid
-    bit-for-bit; ``g > 1`` amortizes per-invocation overhead (grid
-    bookkeeping, output DMA turnaround) across g solves — the batch-grid
-    tuning knob for small-bucket fleets where dispatch dominates.  Each
-    graph's rows are read and written through the refs at a dynamic index
-    on the untiled leading axis, so any g tiles.
+    Per-graph vectors are (g, m) tiles, one graph per sublane row, and
+    per-graph scalars (g, 1) columns, so one vector op serves every graph
+    of the group.  Each loop iteration is one MTCV step of every graph:
+    the masked score, a first-max argmax per row (ties broken as
+    ``jnp.argmax`` breaks them) and ``conn += adj[j, v_j, :]``.  A graph
+    with fewer alive vertices than the group's largest finishes early and
+    is masked, as vmap's while rule masks finished lanes.  The only
+    per-graph work is the row read and the Algorithm 1 merge, which visit
+    the graphs a tile of 8 at a time: a one-hot mask over the graph's
+    (m, m) block reduced over its rows (rows and columns are the same by
+    symmetry), so no vertex index ever leaves the vector unit.
     """
+    f32 = jnp.float32
+    n_alive_max = nmax_ref[pl.program_id(0)]
 
-    def solve_j(j, carry):
-        cut, mask = _solve_graph(
-            adj_ref[j], wl_ref[j], wc_ref[j], pin_ref[j] > 0.5, n=n
+    col = jax.lax.broadcasted_iota(jnp.int32, (g, m), 1).astype(f32)
+    row_i = jax.lax.broadcasted_iota(jnp.int32, (m, m), 0).astype(f32)
+    col_i = jax.lax.broadcasted_iota(jnp.int32, (m, m), 1).astype(f32)
+    eye = row_i == col_i
+    tile_row = jax.lax.broadcasted_iota(jnp.int32, (8, m), 0)
+
+    def total(v):
+        return jnp.sum(v, axis=1, keepdims=True)  # (g, m) → (g, 1)
+
+    def pick(v, hit):
+        # the one entry of each row where ``hit`` holds (exact: the rest are 0)
+        return total(jnp.where(hit, v, 0.0))
+
+    def first_max(v):
+        hit = v == jnp.max(v, axis=1, keepdims=True)
+        return jnp.min(jnp.where(hit, col, f32(m)), axis=1, keepdims=True)
+
+    def tiles(fn):
+        # fn(base) for each tile of 8 graphs base .. base + 7
+        def tile(c, carry):
+            fn(pl.multiple_of(c * 8, 8))
+            return carry
+
+        jax.lax.fori_loop(0, g // 8, tile, 0)
+
+    def read_row(j):
+        hit = row_i == idx_ref[pl.ds(j, 1), :]
+        return jnp.sum(jnp.where(hit, adj_ref[j], 0.0), axis=0, keepdims=True)
+
+    def read_rows(idx):
+        """(g, 1) vertex indices → (g, m) rows ``adj[j, idx_j, :]``."""
+        idx_ref[...] = jnp.broadcast_to(idx, (g, m))
+
+        def tile(base):
+            rows = jnp.zeros((8, m), f32)
+            for r in range(8):
+                rows = jnp.where(tile_row == r, read_row(base + r), rows)
+            row_ref[pl.ds(base, 8), :] = rows
+
+        tiles(tile)
+        return row_ref[...]
+
+    def merge_tile(base):
+        for r in range(8):
+            merge(base + r)
+
+    def merge(j):
+        """Algorithm 1 on graph j: fold t (idx_ref, its row in row_ref)
+        into s (s_ref); both are −1 for a graph with no phase left."""
+        s = s_ref[pl.ds(j, 1), :]
+        t = idx_ref[pl.ds(j, 1), :]
+        t_row = row_ref[pl.ds(j, 1), :]
+        t_col = jnp.sum(jnp.where(eye, t_row, 0.0), axis=1, keepdims=True)
+        s_rows, s_cols = row_i == s, col_i == s
+        a = adj_ref[j]
+        a = jnp.where(s_rows, a + t_row, a)
+        a = jnp.where(s_cols, a + t_col, a)
+        a = jnp.where(s_rows & s_cols, 0.0, a)
+        adj_ref[j] = jnp.where((row_i == t) | (col_i == t), 0.0, a)
+
+    def phase(p, carry):
+        wl, wc, alive, label, src, n_alive, best_cut, best_cloud = carry
+        gains = wl - wc
+        valid = n_alive >= 2.0
+        cand0 = alive > 0.5
+
+        def step(i, inner):
+            in_a, conn, s_reg, t_reg = inner
+            do = (i + 1).astype(f32) < n_alive
+            scores = jnp.where(cand0 & (in_a < 0.5), conn - gains, NEG_INF)
+            v = first_max(scores)
+            in_a = jnp.where(do & (col == v), 1.0, in_a)
+            conn = jnp.where(do, conn + read_rows(v), conn)
+            return in_a, conn, jnp.where(do, t_reg, s_reg), jnp.where(do, v, t_reg)
+
+        in_a0 = jnp.where(col == src, alive, 0.0)
+        _, _, s_reg, t_reg = jax.lax.fori_loop(
+            0, n_alive_max - 1 - p, step, (in_a0, read_rows(src), src, src)
         )
-        cut_ref[j] = cut
-        mask_ref[j] = mask
-        return carry
 
-    jax.lax.fori_loop(0, g, solve_j, 0)
+        # Eq. 10 cut-of-the-phase; −1 marks a graph with no phase left
+        s_reg = jnp.where(valid, s_reg, -1.0)
+        t_reg = jnp.where(valid, t_reg, -1.0)
+        t_row = read_rows(t_reg)
+        t_hit = col == t_reg
+        cut = ctot - pick(gains, t_hit) + total(t_row * alive)
+        cloud_t = label == t_reg
+        improved = valid & (cut < best_cut)
+        best_cut = jnp.where(improved, cut, best_cut)
+        best_cloud = jnp.where(improved, cloud_t.astype(f32), best_cloud)
+
+        # Algorithm 1 merge of (s, t)
+        s_ref[...] = jnp.broadcast_to(s_reg, (g, m))
+        tiles(merge_tile)
+        s_hit = col == s_reg
+        wl = jnp.where(t_hit, 0.0, jnp.where(s_hit, wl + pick(wl, t_hit), wl))
+        wc = jnp.where(t_hit, 0.0, jnp.where(s_hit, wc + pick(wc, t_hit), wc))
+        alive = jnp.where(t_hit, 0.0, alive)
+        label = jnp.where(cloud_t, s_reg, label)
+        src = jnp.where(t_reg == src, s_reg, src)
+        n_alive = n_alive - valid.astype(f32)
+        return wl, wc, alive, label, src, n_alive, best_cut, best_cloud
+
+    ctot = ctot_ref[...]
+    alive0 = alive_ref[...]
+    carry0 = (
+        wl_ref[...], wc_ref[...], alive0, label_ref[...], src_ref[...],
+        total(alive0), jnp.full((g, 1), POS_INF, f32), jnp.zeros((g, m), f32),
+    )
+    out = jax.lax.fori_loop(0, n_alive_max - 1, phase, carry0)
+    cut_ref[...] = out[6]
+    mask_ref[...] = 1.0 - out[7]
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "block_graphs"))
-def _sw_call(adj, wl, wc, pin, *, interpret: bool, block_graphs: int = 1):
-    b, n, _ = adj.shape
-    g = block_graphs
-    assert b % g == 0, (b, g)
-    body = functools.partial(_sw_block_body, n=n, g=g)
-    row = pl.BlockSpec((g, 1, n), lambda i: (i, 0, 0))
+def lockstep_phases(adj, wl, wc, alive, label, src, ctot, *, group: int, interpret: bool):
+    """The phases of B folded graphs, ``group`` graphs per lockstep chain.
+
+    Traced inside the caller's jit (``core.mcop._mcop_batch_impl_lockstep``,
+    which folds the pinned vertices first): ``adj`` (B, m, m), ``wl``,
+    ``wc``, ``alive`` (1.0 = alive) and ``label`` (B, m), ``src`` and
+    ``ctot`` (B,), all float32, B a multiple of ``group``, itself a
+    multiple of 8.  Each group's adjacencies are loaded into VMEM once and
+    merged there in place.  Returns ``(cuts (B,), masks (B, m) f32)``.
+    """
+    b, m = wl.shape
+    g = group
+    assert b % g == 0 and g % 8 == 0, (b, g)
+    n_alive = jnp.sum(alive, axis=1).astype(jnp.int32)
+    nmax = jnp.max(n_alive.reshape(b // g, g), axis=1)
+    vec = pl.BlockSpec((g, m), lambda i, _: (i, 0))
+    one = pl.BlockSpec((g, 1), lambda i, _: (i, 0))
     cut, mask = pl.pallas_call(
-        body,
-        grid=(b // g,),
-        in_specs=[pl.BlockSpec((g, n, n), lambda i: (i, 0, 0)), row, row, row],
-        out_specs=[pl.BlockSpec((g, 1, 1), lambda i: (i, 0, 0)), row],
+        functools.partial(_lockstep_body, m=m, g=g),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b // g,),
+            in_specs=[
+                pl.BlockSpec((g, m, m), lambda i, _: (i, 0, 0)),
+                vec, vec, vec, vec, one, one,
+            ],
+            out_specs=[one, vec],
+            scratch_shapes=[pltpu.VMEM((g, m), jnp.float32) for _ in range(3)],
+        ),
         out_shape=[
-            jax.ShapeDtypeStruct((b, 1, 1), jnp.float32),
-            jax.ShapeDtypeStruct((b, 1, n), jnp.float32),
+            jax.ShapeDtypeStruct((b, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b, m), jnp.float32),
         ],
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
-    )(adj, wl[:, None, :], wc[:, None, :], pin[:, None, :])
-    return cut[:, 0, 0], mask[:, 0, :] > 0.5
+    )(nmax, adj, wl, wc, alive, label, src[:, None], ctot[:, None])
+    return cut[:, 0], mask
 
 
-def default_block_graphs(n: int, interpret: bool) -> int:
-    """Graphs per program invocation for an n-vertex bucket.
-
-    Compiled kernels amortize per-invocation overhead by solving several
-    graphs per grid step: target ~2048 "vertex rows" of work per program,
-    capped at 8 graphs and by the VMEM budget (the double-buffered input
-    block plus the n²-sized working arrays must fit).  The interpreter executes the
-    grid serially with no per-step launch cost, so it keeps the
-    historical 1-graph grid.  ``REPRO_MCOP_BLOCK_GRAPHS`` overrides both
-    (the hillclimbing knob for real-TPU tuning).
-    """
+def _block_override() -> int | None:
+    """``REPRO_MCOP_BLOCK_GRAPHS``, the graphs-per-block override both
+    kernels honour, or ``None`` when unset."""
     import os
 
     override = os.environ.get("REPRO_MCOP_BLOCK_GRAPHS")
+    if override is None:
+        return None
+    g = int(override)
+    if g < 1:
+        raise ValueError(f"REPRO_MCOP_BLOCK_GRAPHS must be >= 1, got {g}")
+    return g
+
+
+def default_block_graphs(n: int) -> int:
+    """Graphs per lockstep group for an n-vertex bucket.
+
+    A group's chain of dependent steps costs the same whatever the group
+    holds, so the group is as large as VMEM allows: whole tiles of 8
+    graphs whose double-buffered adjacency block, with the n²-sized
+    temporaries of the row reads and merges, fits the budget (88 graphs
+    of 128 vertices, 16 of 256), capped at 256.  Batches smaller than a
+    group run as one group of their own size.  ``REPRO_MCOP_BLOCK_GRAPHS``
+    overrides it.
+    """
+    override = _block_override()
     if override is not None:
-        g = int(override)
-        if g < 1:
-            raise ValueError(f"REPRO_MCOP_BLOCK_GRAPHS must be >= 1, got {g}")
-        return g
+        return override
+    g = 256
+    while g > 8 and _blocked_vmem_bytes(n, g) > _VMEM_BYTES:
+        g -= 8
+    return g
+
+
+def lockstep_group(b: int, n: int, block_graphs: int | None = None) -> int:
+    """The lockstep group for a batch of b n-vertex graphs: ``block_graphs``
+    (``None`` = :func:`default_block_graphs`) rounded up to a whole tile
+    of 8, and no larger than the batch rounded up to one."""
+    g = default_block_graphs(n) if block_graphs is None else int(block_graphs)
+    g = min(-(-max(g, 1) // 8) * 8, -(-max(b, 1) // 8) * 8)
+    assert _blocked_vmem_bytes(n, g) <= _VMEM_BYTES, (
+        f"graph too large for single-core VMEM with kernel working set: "
+        f"n={n}, block_graphs={g}"
+    )
+    return g
+
+
+def _fused_block_graphs(n: int, interpret: bool) -> int:
+    """Graphs per grid step of the fused kernel, which solves them one
+    after another: ~2048 "vertex rows" of work per step, at most 8 graphs
+    and within the VMEM budget when compiled, 1 in the interpreter (no
+    per-step launch cost to amortize).  ``REPRO_MCOP_BLOCK_GRAPHS``
+    overrides it."""
+    override = _block_override()
+    if override is not None:
+        return override
     if interpret:
         return 1
     g = max(1, min(8, 2048 // max(n, 1)))
@@ -327,53 +511,6 @@ def default_block_graphs(n: int, interpret: bool) -> int:
 
 def _pad_batch(b: int, g: int) -> int:
     return (-b) % g
-
-
-def mcop_stoer_wagner_kernel(
-    adj: jnp.ndarray,       # (B, n, n) f32 — a batch of WCG adjacencies
-    w_local: jnp.ndarray,   # (B, n)
-    w_cloud: jnp.ndarray,   # (B, n)
-    pinned: jnp.ndarray,    # (B, n) bool/f32 — True = unoffloadable
-    *,
-    interpret: bool | None = None,
-    block_graphs: int | None = None,
-) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Solve a batch of MCOP instances entirely on-device.
-
-    ``block_graphs`` graphs per grid step (``None`` = auto, see
-    :func:`default_block_graphs`); within a step each adjacency lives in
-    VMEM for its whole |V|−1-phase run (single HBM load per solve).
-    Batches that don't divide the block are zero-padded with pinned
-    dummy graphs and cropped after.  Returns ``(min_cuts (B,),
-    local_masks (B, n) bool)`` — semantics match
-    :func:`repro.core.mcop.mcop_reference` (same heuristic, same
-    tie-breaking, f32 arithmetic), independent of ``block_graphs``.
-    Dead/padded vertices must be encoded as pinned with zero weights and
-    zero incident edges.
-    """
-    adj = jnp.asarray(adj, jnp.float32)
-    assert adj.ndim == 3, f"expected (B, n, n) batch, got {adj.shape}"
-    b, n = adj.shape[0], adj.shape[-1]
-    interp = _resolve_interpret(interpret)
-    g = default_block_graphs(n, interp) if block_graphs is None else int(block_graphs)
-    g = max(1, min(g, b if b else 1))
-    assert _blocked_vmem_bytes(n, g) <= _VMEM_BYTES, (
-        f"graph too large for single-core VMEM with kernel working set: "
-        f"n={n}, block_graphs={g}"
-    )
-    wl = jnp.asarray(w_local, jnp.float32).reshape(b, n)
-    wc = jnp.asarray(w_cloud, jnp.float32).reshape(b, n)
-    pin = jnp.asarray(pinned, jnp.float32).reshape(b, n)
-    pad = _pad_batch(b, g)
-    if pad:
-        adj = jnp.concatenate([adj, jnp.zeros((pad, n, n), jnp.float32)])
-        wl = jnp.concatenate([wl, jnp.zeros((pad, n), jnp.float32)])
-        wc = jnp.concatenate([wc, jnp.zeros((pad, n), jnp.float32)])
-        pin = jnp.concatenate([pin, jnp.ones((pad, n), jnp.float32)])
-    cuts, masks = _sw_call(adj, wl, wc, pin, interpret=interp, block_graphs=g)
-    if pad:
-        cuts, masks = cuts[:b], masks[:b]
-    return cuts, masks
 
 
 # ======================================================================
@@ -551,7 +688,7 @@ def mcop_fused_solve_kernel(
     k = env.shape[0]
     n = int(t_local.shape[-1])
     interp = _resolve_interpret(interpret)
-    g = default_block_graphs(n, interp) if block_graphs is None else int(block_graphs)
+    g = _fused_block_graphs(n, interp) if block_graphs is None else int(block_graphs)
     g = max(1, min(g, k if k else 1))
     # working set: 4 double-buffered n² profile blocks + the solver arrays
     assert _blocked_vmem_bytes(n, 4) <= _VMEM_BYTES, (

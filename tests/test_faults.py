@@ -444,6 +444,53 @@ def test_breaker_escalates_failing_backend_mid_tick(monkeypatch):
     assert fut.result.result is not None and not fut.result.degraded
 
 
+def test_breaker_reaches_reference_when_the_lockstep_kernel_faults(monkeypatch):
+    """Where the default path runs the lockstep kernel (a TPU, float32,
+    bucket ≥ 64: forced here), "pallas" and "jax" both run it, so a
+    kernel fault opens both circuits in turn and the flush is served by
+    the numpy reference, exactly, not degraded."""
+    import sys
+
+    from repro.kernels import mcop_phase
+
+    mcop_mod = sys.modules["repro.core.mcop"]  # ``repro.core.mcop`` is also a function
+    monkeypatch.setattr(mcop_mod, "_solver_for", lambda platform, m, dtype: "lockstep")
+
+    def kernel_fault(*args, **kw):
+        raise RuntimeError("Mosaic kernel fault")
+
+    monkeypatch.setattr(mcop_phase, "lockstep_phases", kernel_fault)
+    breaker = CircuitBreaker(threshold=2, cooldown_ticks=4)
+    broker = _broker(
+        backend="pallas",  # escalation chain: pallas → jax → reference
+        resilience=_policy(retry=RetryPolicy(max_retries=4), breaker=breaker),
+    )
+    profile = _profile(70, 5)  # bucket 128
+    broker.register("app", profile, ResponseTimeModel())
+
+    backends_used = []
+    from repro.service import broker as broker_mod
+
+    real = broker_mod.mcop_batch
+
+    def spy(batch, *, backend, buckets, **kw):
+        backends_used.append(backend)
+        return real(batch, backend=backend, buckets=buckets, **kw)
+
+    monkeypatch.setattr(broker_mod, "mcop_batch", spy)
+    fut = broker.submit("app", _env())
+    report = broker.tick()
+    assert report.buckets == (128,)
+    assert backends_used == ["pallas", "pallas", "jax", "jax", "reference"]
+    assert report.breaker_trips == 2 and report.retries == 4
+    assert fut.result.result is not None and not fut.result.degraded
+    clean = _broker()
+    clean.register("app", profile, ResponseTimeModel())
+    want = clean.submit("app", _env())
+    clean.tick()
+    assert _reply_tuple(fut.result) == _reply_tuple(want.result)
+
+
 def test_latency_faults_charge_injected_clock_only():
     clock = InjectedClock()
     faults = ScriptedFaultInjector(

@@ -229,7 +229,7 @@ def test_mcop_kernel_compiled_noninterpret_path(monkeypatch):
     other platforms skip; on TPU a compiler refusal fails the test, and
     the compiled tier is pinned to the interpret tier bitwise."""
     from repro.kernels import ops
-    from repro.kernels.mcop_phase import mcop_stoer_wagner_kernel
+    from repro.core.mcop import mcop_stoer_wagner_kernel
 
     if jax.devices()[0].platform != "tpu":
         pytest.skip("compiled Pallas kernels need a TPU backend")
@@ -248,14 +248,12 @@ def test_mcop_kernel_compiled_noninterpret_path(monkeypatch):
 
 
 def test_mcop_kernel_block_graphs_bitwise_invariant():
-    """The blocked grid (g graphs per program instance) is a pure
-    scheduling choice: g=1, g=3 (forces tail padding on b=10) and the
-    auto choice must produce bit-identical cuts and masks, all matching
-    the numpy oracle."""
-    from repro.kernels.mcop_phase import (
-        default_block_graphs,
-        mcop_stoer_wagner_kernel,
-    )
+    """The lockstep group is a pure scheduling choice: g=1 and g=3 (both
+    whole tiles of 8: two groups for b=10, the second padded) and the
+    auto choice (one group of 16) must produce bit-identical cuts and
+    masks, all matching the numpy oracle."""
+    from repro.core.mcop import mcop_stoer_wagner_kernel
+    from repro.kernels.mcop_phase import default_block_graphs
 
     graphs, adj, wl, wc, pin = _sw_batch()
     runs = {}
@@ -272,17 +270,17 @@ def test_mcop_kernel_block_graphs_bitwise_invariant():
         assert base_cuts[i] == pytest.approx(
             mcop_reference(wcg).min_cut, rel=1e-5
         )
-    assert default_block_graphs(16, True) == 1  # interpret stays g=1
+    assert default_block_graphs(16) == 256  # a 16-vertex group fills VMEM at 256
 
 
 def test_mcop_kernel_block_graphs_env_override(monkeypatch):
     from repro.kernels.mcop_phase import default_block_graphs
 
     monkeypatch.setenv("REPRO_MCOP_BLOCK_GRAPHS", "4")
-    assert default_block_graphs(16, True) == 4
+    assert default_block_graphs(16) == 4
     monkeypatch.setenv("REPRO_MCOP_BLOCK_GRAPHS", "0")
     with pytest.raises(ValueError):
-        default_block_graphs(16, True)
+        default_block_graphs(16)
 
 
 def test_fused_kernel_solve_envs_parity():

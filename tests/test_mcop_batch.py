@@ -149,7 +149,7 @@ def test_mcop_batch_rejects_unknown_backend():
 
 
 def test_full_kernel_direct_paper_example():
-    from repro.kernels import mcop_stoer_wagner_kernel
+    from repro.core.mcop import mcop_stoer_wagner_kernel
 
     g = paper_example_graph()
     cuts, masks = mcop_stoer_wagner_kernel(

@@ -336,3 +336,56 @@ def test_fleet_flush_packs_in_a_span_and_names_its_programs():
         """,
         devices=4,
     )
+
+
+@pytest.mark.parametrize("devices", [4, 8])
+def test_fleet_flush_with_the_lockstep_kernel_bit_identical(devices):
+    """The rule forced to the lockstep kernel (as on a TPU, here in
+    interpret mode): granite's bucket-128 flush on the fleet, through
+    the packed path and the fused one, is bit-identical to the
+    one-device flush, and each wait names the kernel."""
+    run_sub(
+        f"""
+        import sys
+        import numpy as np, jax
+        from repro.configs import get_config
+        from repro.configs.base import ShapeConfig
+        from repro.core import Environment, ResponseTimeModel
+        from repro.core.cost_models import EnvArrays
+        from repro.core.graph import WCGBatch
+        from repro.core.mcop_shard import default_solver_mesh
+        from repro.obs import Tracer
+        from repro.profilers.program import app_profile_from_config
+
+        mcop = sys.modules['repro.core.mcop']
+        assert jax.device_count() == {devices}
+        mesh = default_solver_mesh()
+        profile = app_profile_from_config(
+            get_config('granite-34b'), ShapeConfig('decode_8k', 'decode', 8192, 128),
+            local_flops_per_s=1e13)
+        rng = np.random.default_rng(17)
+        k = 13  # uneven on the fleet: inert padding + round-robin
+        envs = EnvArrays(10 ** rng.uniform(0.5, 3.5, k), 10 ** rng.uniform(0.5, 3.5, k),
+                         rng.uniform(1.5, 12.0, k), *(rng.uniform(0.1, 2.0, k) for _ in range(3)))
+        batch = ResponseTimeModel().build_batch(profile, envs, m=128)
+        xla = mcop.mcop_batch(batch, mesh=False)
+
+        mcop._solver_for = lambda platform, m, dtype: 'lockstep'
+        tr = Tracer()
+        sharded = mcop.mcop_batch(batch, mesh=mesh, tracer=tr)
+        single = mcop.mcop_batch(batch, mesh=False, tracer=tr)
+        fused_sh = mcop.solve_envs(profile, ResponseTimeModel(), envs, mesh=mesh, tracer=tr)
+        fused_1 = mcop.solve_envs(profile, ResponseTimeModel(), envs, mesh=False, tracer=tr)
+        for rs, r1, rf, rf1, rx in zip(sharded, single, fused_sh, fused_1, xla, strict=True):
+            assert rs.min_cut == r1.min_cut
+            assert np.array_equal(rs.local_mask, r1.local_mask)
+            assert rf.min_cut == rf1.min_cut
+            assert np.array_equal(rf.local_mask, rf1.local_mask)
+            assert r1.min_cut == rx.min_cut
+            assert np.array_equal(r1.local_mask, rx.local_mask)
+        assert [w.attrs['solver'] for w in tr.spans('solve.wait')] == ['lockstep'] * 4
+        assert len(tr.spans('solve.shard')) == {devices}
+        print('OK')
+        """,
+        devices=devices,
+    )

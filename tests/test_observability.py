@@ -479,13 +479,13 @@ def test_telemetry_fields_mirror_registry_counters():
     h = metrics.get_histogram("broker_tick_latency_s")
     assert h is not None and h.count == tel.ticks
     assert tel.tick_latency_quantiles() == (h.p50, h.p90, h.p99)
-    # solver dispatches carried (backend, bucket) labels
+    # solver dispatches carried (backend, bucket, devices, solver) labels
     snap = metrics.snapshot()
     dispatch_rows = [
         c for c in snap["counters"] if c["name"] == "solve_envs_dispatches"
     ]
     assert tel.dispatches == 0 or dispatch_rows == [] or all(
-        set(c["labels"]) == {"backend", "bucket", "devices"}
+        set(c["labels"]) == {"backend", "bucket", "devices", "solver"}
         for c in dispatch_rows
     )
     # queue gauges were published

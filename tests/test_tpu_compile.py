@@ -19,13 +19,12 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
 
-from repro.core.mcop import _mcop_batch_jit
+from repro.core.mcop import _mcop_batch_jit, mcop_stoer_wagner_kernel
 from repro.core.mcop_shard import _sharded_dispatch
 from repro.kernels.mcop_phase import (
     FUSED_MODEL_KINDS,
     default_block_graphs,
     mcop_fused_solve_kernel,
-    mcop_stoer_wagner_kernel,
 )
 from repro.launch.mesh import make_solver_mesh
 from repro.runtime.sharding import solve_batch_spec
@@ -75,7 +74,7 @@ def test_stoer_wagner_kernel_compiles(one_chip, n):
     )
     compiled = solve.lower(adj, wl, wc, pin).compile()
     assert _has_kernel(compiled)
-    assert default_block_graphs(n, False) == 8
+    assert default_block_graphs(n) == {16: 256, 64: 256, 128: 88, 256: 16}[n]
 
 
 @pytest.mark.parametrize("n", [16, 64, 128, 256])
